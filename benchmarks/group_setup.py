@@ -3,7 +3,9 @@
 Paper (NCCL, 8-GPU): new_group ~0.5 ms; FIRST collective 217-778 ms cold
 init + ~0.5 GB/GPU; warm collective fast; GFC registration ~60 us.
 
-JAX/TPU mapping measured here (8 host devices, subprocess):
+JAX mapping measured here on 8 forced host CPU devices (subprocess pinned
+to JAX_PLATFORMS=cpu).  Every row is a host-device number, labelled
+``host_cpu`` in its derived column, and never a chip measurement:
   cold_compile   = build Mesh + jit + compile a subgroup collective for a
                    NEW group (the XLA analogue of NCCL cold init)
   cache_hit      = same-size different-members group through the
@@ -101,6 +103,8 @@ print(json.dumps(out))
 def run() -> dict:
     env = dict(os.environ)
     env["PYTHONPATH"] = str(Path(__file__).resolve().parents[1] / "src")
+    # forced host CPU devices: the child must never contend for a chip
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run([sys.executable, "-c", _CHILD], env=env,
                           capture_output=True, text=True, timeout=600)
     if proc.returncode != 0:
@@ -132,7 +136,7 @@ def rows(data: dict) -> list[tuple[str, float, str]]:
                 nonzero or "telemetry_histogram"))
     out.append(("group_setup.warm_collective", data["warm_collective_us"],
                 "steady_state"))
-    return out
+    return [(name, v, f"host_cpu;{derived}") for name, v, derived in out]
 
 
 if __name__ == "__main__":

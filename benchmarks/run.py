@@ -64,6 +64,8 @@ def main() -> None:
                     help="run only suites whose label contains this "
                          "substring (default: all)")
     args = ap.parse_args()
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     if args.suite:
         suites = [(label, mod) for label, mod in suites
                   if args.suite.lower() in label.lower()]

@@ -45,6 +45,7 @@ import argparse
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.configs.dit_models import DIT_IMAGE
 from repro.core.policies import EDFPolicy, ElasticPolicy, make_policy
 from repro.core.trajectory import Request
@@ -81,7 +82,8 @@ def main():
                          "KV-gather path)")
     ap.add_argument("--use-pallas", action="store_true",
                     help="serve through the fused Pallas kernel layer "
-                         "(DESIGN.md §12; interpret mode off-TPU)")
+                         "(DESIGN.md §12; compiled on a TPU, interpret "
+                         "mode elsewhere)")
     ap.add_argument("--cfg-split", action="store_true",
                     help="serve guided requests (classifier-free "
                          "guidance) under the hybrid shape-searching "
@@ -102,6 +104,7 @@ def main():
     args = ap.parse_args()
     if not 0.0 <= args.sample_rate <= 1.0:
         raise SystemExit("--sample-rate must be in [0, 1]")
+    use_compile_cache()
 
     if args.cfg_split:
         if args.policy == "edf":

@@ -3,10 +3,9 @@
 Workers are threads (rank = thread); model executors run REAL JAX compute
 on token shards with GFC collectives inside (sequence parallelism), so the
 distributed semantics — dynamic groups, per-layer subgroup all-gathers,
-layout migration — are executed faithfully.  Wall-clock speedup is not
-observable on this 1-core container (documented in DESIGN.md §8); the
-simulator supplies calibrated timing, and this backend supplies
-correctness + overhead measurements.
+layout migration — are executed faithfully.  Each worker computes on its
+own local device (:func:`rank_device`); with a single device every rank
+shares it.
 """
 from __future__ import annotations
 
@@ -17,12 +16,21 @@ import traceback
 from dataclasses import dataclass
 from typing import Any, Optional
 
+import jax
+
 from repro.core.gfc import CollectiveTimeout, GroupFreeComm
 from repro.core.migration import (execute_migration, layout_moved,
                                   plan_migration)
 from repro.core.scheduler import Completion
 from repro.core.trajectory import (ExecutionLayout, RequestGraph,
                                    TrajectoryTask)
+
+
+def rank_device(rank: int):
+    """The local device rank ``rank`` computes on: ranks wrap over
+    ``jax.local_devices()``, so with one device every rank shares it."""
+    devices = jax.local_devices()
+    return devices[rank % len(devices)]
 
 
 @dataclass
@@ -74,6 +82,10 @@ class ThreadBackend:
 
     # ------------------------------------------------------------------
     def _worker(self, rank: int):
+        with jax.default_device(rank_device(rank)):
+            self._serve_queue(rank)
+
+    def _serve_queue(self, rank: int):
         while not self._stop:
             try:
                 job = self._queues[rank].get(timeout=0.01)

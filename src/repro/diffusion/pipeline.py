@@ -9,6 +9,7 @@ the cost model.
 from __future__ import annotations
 
 import hashlib
+import threading
 from typing import Any, Optional
 
 import jax
@@ -16,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.core.executor import rank_device
 from repro.core.gfc import GroupDescriptor, GroupFreeComm
 from repro.core.trajectory import (ExecutionLayout, RequestGraph,
                                    TrajectoryTask)
@@ -32,7 +34,8 @@ def _req_seed(request_id: str) -> int:
 
 
 class DiTPipeline:
-    """Executable DiT pipeline with reduced weights (CPU-runnable)."""
+    """Executable DiT pipeline: the configured denoiser plus a toy text
+    encoder and VAE decoder, with seeded random weights."""
 
     def __init__(self, cfg: ModelConfig, seed: int = 0):
         assert cfg.family == "dit"
@@ -47,6 +50,19 @@ class DiTPipeline:
         self.txt_params, _ = split_params(
             text_encoder.init(ks[1], self.txt_cfg))
         self.vae_params, _ = split_params(vae.init(ks[2], cfg, hidden=32))
+        self._placed: dict = {}
+        self._place_lock = threading.Lock()
+
+    def weights(self, rank: int):
+        """(dit, text, vae) parameter trees on ``rank``'s device, copied
+        there once, on first use — so the weights must not change once
+        serving has started."""
+        dev = rank_device(rank)
+        with self._place_lock:
+            if dev not in self._placed:
+                self._placed[dev] = jax.device_put(
+                    (self.dit_params, self.txt_params, self.vae_params), dev)
+            return self._placed[dev]
 
     # ------------------------------------------------------------------
     # adapter interface: execute this rank's share of a trajectory task
@@ -56,12 +72,12 @@ class DiTPipeline:
                 desc: GroupDescriptor):
         if task.kind == "encode":
             if rank == layout.ranks[0]:
-                self._encode(task, layout, graph)
+                self._encode(task, layout, rank, graph)
         elif task.kind == "denoise":
             self._denoise(task, layout, rank, comm, graph, desc)
         elif task.kind == "decode":
             if rank == layout.ranks[0]:
-                self._decode(task, layout, graph)
+                self._decode(task, layout, rank, graph)
         else:
             raise ValueError(task.kind)
 
@@ -138,7 +154,7 @@ class DiTPipeline:
         x = jnp.stack([jnp.asarray(s) for s in xs])        # (B, N_loc, pd)
         txt = jnp.stack([jnp.asarray(s) for s in txts])    # (B, Lt, cond)
         v = dit.forward_sp_tokens(
-            self.dit_params, x, t, txt, self.cfg, pos_offset=off,
+            self.weights(rank)[0], x, t, txt, self.cfg, pos_offset=off,
             n_total=n_total, kv_gather=kv_gather)
         for i, (task, graph) in enumerate(members):
             s_now, s_next = sig_pairs[i]
@@ -149,14 +165,15 @@ class DiTPipeline:
             out_art.data[rank]["sigma"] = np.float32(s_next)
 
     # ------------------------------------------------------------------
-    def _encode(self, task, layout, graph):
+    def _encode(self, task, layout, rank, graph):
+        txt_params = self.weights(rank)[1]
         req = graph.request
         seed = _req_seed(req.id)
         key = jax.random.PRNGKey(seed)
         # synthetic prompt tokens derived from the request id (length 77
         # matches the converter's declared text_embeds field shape)
         toks = jax.random.randint(key, (1, 77), 0, self.txt_cfg.vocab_size)
-        embeds = text_encoder.encode(self.txt_params, toks, self.txt_cfg,
+        embeds = text_encoder.encode(txt_params, toks, self.txt_cfg,
                                      dtype=jnp.float32)[0]     # (Lt, cond)
         txt_art = graph.artifacts[task.outputs[0]]
         # replicated field: every rank of this layout holds a copy (a
@@ -167,7 +184,7 @@ class DiTPipeline:
             # classifier-free guidance (DESIGN.md §14): the uncond branch
             # conditions on the null prompt (all-zero tokens)
             toks_u = jnp.zeros_like(toks)
-            emb_u = text_encoder.encode(self.txt_params, toks_u,
+            emb_u = text_encoder.encode(txt_params, toks_u,
                                         self.txt_cfg,
                                         dtype=jnp.float32)[0]
             for r in layout.ranks:
@@ -253,7 +270,7 @@ class DiTPipeline:
                 return jnp.asarray(K), jnp.asarray(V)
 
         v_shard = dit.forward_sp_tokens(
-            self.dit_params, jnp.asarray(x_shard)[None], t,
+            self.weights(rank)[0], jnp.asarray(x_shard)[None], t,
             jnp.asarray(txt)[None], self.cfg, pos_offset=off,
             n_total=n_total, kv_gather=kv_gather)[0]
         new_x = schedule.flow_step(jnp.asarray(x_shard), v_shard,
@@ -309,7 +326,7 @@ class DiTPipeline:
             txt = jnp.stack([jnp.asarray(txt_c), jnp.asarray(txt_u)])
             t = jnp.array([ts, ts], jnp.float32)
             v = dit.forward_sp_tokens(
-                self.dit_params, x, t, txt, self.cfg, pos_offset=off,
+                self.weights(rank)[0], x, t, txt, self.cfg, pos_offset=off,
                 n_total=n_total, kv_gather=kv_gather)
             v_c, v_u = np.asarray(v[0]), np.asarray(v[1])
         else:
@@ -330,7 +347,7 @@ class DiTPipeline:
             txt = txt_c if b == 0 else txt_u
             t = jnp.array([ts], jnp.float32)
             v_mine = dit.forward_sp_tokens(
-                self.dit_params, jnp.asarray(x_shard)[None], t,
+                self.weights(rank)[0], jnp.asarray(x_shard)[None], t,
                 jnp.asarray(txt)[None], self.cfg, pos_offset=off,
                 n_total=n_total, kv_gather=kv_gather)[0]
             # the one guidance-merge exchange: branch peers sharing this
@@ -347,7 +364,7 @@ class DiTPipeline:
         out_art.data[rank]["sigma"] = np.float32(sigma_next)
 
     # ------------------------------------------------------------------
-    def _decode(self, task, layout, graph):
+    def _decode(self, task, layout, rank, graph):
         lat_art = graph.artifacts[task.inputs[0]]
         out_art = graph.artifacts[task.outputs[0]]
         leader = layout.ranks[0]
@@ -373,7 +390,7 @@ class DiTPipeline:
             self._infer_latent_shape(graph)
         lat = dit.unpatchify(jnp.asarray(tokens)[None],
                              (1, f, h, w, c), self.cfg.dit.patch_size)
-        pixels = vae.decode(self.vae_params, lat, self.cfg)[0]
+        pixels = vae.decode(self.weights(rank)[2], lat, self.cfg)[0]
         out_art.data[leader]["pixels"] = np.asarray(pixels)
 
     def _infer_latent_shape(self, graph):

@@ -34,7 +34,7 @@ def _adaln_kernel(*refs, eps: float, ln: bool, has_mod: bool,
     """One (batch, n-block) program.
 
     refs order: x, [shift, scale], [gate, residual], out.
-    x/residual/out: (block_n, D) VMEM tiles; shift/scale/gate: (D,)
+    x/residual/out: (block_n, D) VMEM tiles; shift/scale/gate: (1, D)
     per-batch modulation rows.
     """
     it = iter(refs)
@@ -53,11 +53,11 @@ def _adaln_kernel(*refs, eps: float, ln: bool, has_mod: bool,
         var = ((x - mu) ** 2).mean(axis=1, keepdims=True)
         x = (x - mu) * jax.lax.rsqrt(var + eps)
     if has_mod:
-        x = x * (1.0 + scale_ref[...].astype(jnp.float32)[None, :]) \
-            + shift_ref[...].astype(jnp.float32)[None, :]
+        x = x * (1.0 + scale_ref[...].astype(jnp.float32)) \
+            + shift_ref[...].astype(jnp.float32)
     if has_gate:
         x = res_ref[...].astype(jnp.float32) \
-            + gate_ref[...].astype(jnp.float32)[None, :] * x
+            + gate_ref[...].astype(jnp.float32) * x
     o_ref[...] = x.astype(o_ref.dtype)
 
 
@@ -81,13 +81,15 @@ def adaln_modulate(x, shift=None, scale=None, gate=None, residual=None, *,
     assert ln or has_mod or has_gate, "identity fusion requested"
 
     tile = pl.BlockSpec((None, block_n, d), lambda i, j: (i, j, 0))
-    row = pl.BlockSpec((None, d), lambda i, j: (i, 0))
+    # modulation rows as (B, 1, D): the block's last two dims then equal
+    # the array's, which the TPU lowering needs at any batch size
+    row = pl.BlockSpec((None, 1, d), lambda i, j: (i, 0, 0))
     operands, in_specs = [x], [tile]
     if has_mod:
-        operands += [shift, scale]
+        operands += [shift[:, None], scale[:, None]]
         in_specs += [row, row]
     if has_gate:
-        operands += [gate, residual]
+        operands += [gate[:, None], residual]
         in_specs += [row, tile]
     grid = (b, n // block_n)
     return pl.pallas_call(

@@ -1,79 +1,170 @@
-"""Flash attention Pallas TPU kernel.
+"""Flash attention Pallas TPU kernel, with the §11 cache splice fused in.
 
-Blockwise online-softmax attention with explicit BlockSpec VMEM tiling:
-the (block_q x d) query tile stays resident while (block_k x d) key/value
-tiles stream through VMEM; running max/denominator keep the softmax
-numerically exact.  MXU alignment: block sizes are multiples of 128 on the
-token dims and head_dim is padded to 128 lanes by the caller if needed
-(``sm_scale`` then carries the UNPADDED head dim's softmax scale).
+Blockwise online-softmax attention over a (batch*head, q-block, k-block)
+grid: the (block_q x d) query tile stays resident while (block_k x d)
+key/value tiles stream through VMEM along the last grid axis, and the
+running max / denominator / accumulator live in VMEM scratch across that
+axis.  VMEM use is a handful of tiles, independent of sequence length.
+MXU alignment: block sizes are multiples of 128 on the token dims and
+head_dim is padded to 128 lanes by the caller if needed (``sm_scale``
+then carries the UNPADDED head dim's softmax scale).
 
-Supports causal masking (block-skipping: fully-masked k-blocks are not
-visited), GQA (q-head group -> kv-head mapping via the grid), and a
-static ``kv_valid`` key-validity bound so callers can zero-pad the key
-axis to the block size without the pad keys leaking probability mass
-(k-blocks past ``kv_valid`` are never visited at all).
+Supports causal masking (k-blocks above the diagonal are neither fetched
+nor computed), GQA (q-head group -> kv-head mapping in the index maps),
+and a static ``kv_valid`` key-validity bound so callers can zero-pad the
+key axis to the block size without the pad keys leaking probability mass
+(k-blocks past ``kv_valid`` are neither fetched nor computed).
+
+The §11 cache-hit path (DESIGN.md §11-§12) attends against the stale
+snapshot with this rank's fresh shard at rows ``[offset, offset + L)``:
+:func:`splice_attention` streams the fresh shard as a second pair of K/V
+tiles, block-aligned to the stale stream, and selects fresh rows per
+tile before the softmax update.  The spliced (N_total) tensor is never
+written; only the L-row fresh shard is re-laid out to block alignment.
 
 TARGET: TPU (pl.pallas_call + BlockSpec).  VALIDATED on CPU with
-``interpret=True`` against ``ref.py``'s pure-jnp oracle.
+``interpret=True`` against ``ref.py``'s pure-jnp oracles.
 """
 from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
 
-def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, causal: bool,
-                 sm_scale: float, seq_k: int, kv_valid: int):
-    """One (batch*head, q-block) program: stream k/v blocks, online softmax.
+def _attn_kernel(*refs, block_q: int, block_k: int, causal: bool,
+                 sm_scale: float, kv_valid: int,
+                 fresh_rows: Optional[tuple[int, int]]):
+    """One (batch*head, q-block, k-block) program.
 
-    q_ref: (block_q, d) VMEM tile      k_ref/v_ref: (seq_k, d) full rows
-    o_ref: (block_q, d) output tile
+    refs: q (block_q, d), k/v (block_k, d), [fresh k/v (block_k, d)],
+    out (block_q, d), then scratch m/l (block_q, 1) and acc (block_q, d).
     """
-    block_q, d = q_ref.shape
-    q_idx = pl.program_id(1)
-    q = q_ref[...].astype(jnp.float32) * sm_scale
-
-    m = jnp.full((block_q,), NEG_INF, jnp.float32)      # running max
-    l = jnp.zeros((block_q,), jnp.float32)              # running denom
-    acc = jnp.zeros((block_q, d), jnp.float32)
-
-    # only k-blocks intersecting the valid key range are visited; the
-    # trailing partial block is mask-corrected below
-    num_k_blocks = -(-kv_valid // block_k)
-
-    def body(kb, carry):
-        m, l, acc = carry
-        k = k_ref[pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        s = q @ k.T                                      # (bq, bk) MXU
-        kpos = kb * block_k + jax.lax.iota(jnp.int32, block_k)
-        if causal:
-            qpos = q_idx * block_q + jax.lax.iota(jnp.int32, block_q)
-            mask = kpos[None, :] <= qpos[:, None]
-            s = jnp.where(mask, s, NEG_INF)
-        if kv_valid % block_k:
-            s = jnp.where((kpos < kv_valid)[None, :], s, NEG_INF)
-        m_new = jnp.maximum(m, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + p.sum(axis=1)
-        acc_new = acc * alpha[:, None] + p @ v
-        return m_new, l_new, acc_new
-
-    if causal:
-        # visit only k-blocks that intersect the causal triangle
-        upper = jax.lax.div((q_idx + 1) * block_q + block_k - 1, block_k)
-        upper = jnp.minimum(upper, num_k_blocks)
+    if fresh_rows is None:
+        q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc = refs
     else:
-        upper = num_k_blocks
-    m, l, acc = jax.lax.fori_loop(0, upper, body, (m, l, acc))
-    o_ref[...] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
+        q_ref, k_ref, v_ref, kf_ref, vf_ref, o_ref, m_sc, l_sc, acc_sc = refs
+    q_idx, k_idx = pl.program_id(1), pl.program_id(2)
+    k_start = k_idx * block_k
+
+    @pl.when(k_idx == 0)
+    def _init():
+        m_sc[...] = jnp.full(m_sc.shape, NEG_INF, jnp.float32)
+        l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
+        acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
+
+    # block 0 always runs and always holds a valid key for every row, so
+    # the running max is finite before any fully-masked block is seen
+    run = k_start < kv_valid
+    if causal:
+        run = jnp.logical_and(run, k_start < (q_idx + 1) * block_q)
+
+    @pl.when(run)
+    def _step():
+        q = q_ref[...].astype(jnp.float32) * sm_scale
+        k = k_ref[...].astype(jnp.float32)
+        v = v_ref[...].astype(jnp.float32)
+        if fresh_rows is not None:
+            lo, hi = fresh_rows
+            row = k_start + jax.lax.broadcasted_iota(jnp.int32,
+                                                     (block_k, 1), 0)
+            fresh = jnp.logical_and(row >= lo, row < hi)
+            k = jnp.where(fresh, kf_ref[...].astype(jnp.float32), k)
+            v = jnp.where(fresh, vf_ref[...].astype(jnp.float32), v)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        if causal or kv_valid % block_k:
+            col = k_start + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1)
+            keep = col < kv_valid
+            if causal:
+                qrow = q_idx * block_q + jax.lax.broadcasted_iota(
+                    jnp.int32, (block_q, block_k), 0)
+                keep = jnp.logical_and(keep, col <= qrow)
+            s = jnp.where(keep, s, NEG_INF)
+        m_prev = m_sc[...]
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_sc[...] = l_sc[...] * alpha + p.sum(axis=1, keepdims=True)
+        acc_sc[...] = acc_sc[...] * alpha + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_sc[...] = m_new
+
+    @pl.when(k_idx == pl.num_programs(2) - 1)
+    def _finish():
+        o_ref[...] = (acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)
+                      ).astype(o_ref.dtype)
+
+
+def _fold_heads(x):
+    """(B, S, H, d) -> (B*H, S, d)."""
+    b, s, h, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+
+
+def _flash_call(q, k, v, fresh, *, causal, block_q, block_k, sm_scale,
+                kv_valid, fresh_rows, interpret):
+    b, sq, h, d = q.shape
+    _, sk, kv, _ = k.shape
+    assert h % kv == 0, (h, kv)
+    group = h // kv
+    assert sq % block_q == 0 and sk % block_k == 0, (sq, sk)
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(d)
+    if kv_valid is None:
+        kv_valid = sk
+    assert 0 < kv_valid <= sk, (kv_valid, sk)
+
+    last_k = -(-kv_valid // block_k) - 1
+
+    def kv_block(qb, kb):
+        # skipped k-blocks repeat the last fetched index, so the pipeline
+        # issues no DMA for them
+        kb = jnp.minimum(kb, last_k)
+        if causal:
+            kb = jnp.minimum(kb, ((qb + 1) * block_q - 1) // block_k)
+        return kb
+
+    q_spec = pl.BlockSpec((None, block_q, d), lambda bh, qb, kb: (bh, qb, 0))
+    kv_spec = pl.BlockSpec((None, block_k, d),
+                           lambda bh, qb, kb: (bh // group,
+                                               kv_block(qb, kb), 0))
+    operands = [_fold_heads(q), _fold_heads(k), _fold_heads(v)]
+    in_specs = [q_spec, kv_spec, kv_spec]
+    if fresh is not None:
+        first, n_win = fresh_rows[0] // block_k, fresh[0].shape[1] // block_k
+        fresh_spec = pl.BlockSpec(
+            (None, block_k, d),
+            lambda bh, qb, kb: (bh // group,
+                                jnp.clip(kb - first, 0, n_win - 1), 0))
+        operands += list(fresh)
+        in_specs += [fresh_spec, fresh_spec]
+
+    out = pl.pallas_call(
+        functools.partial(_attn_kernel, block_q=block_q, block_k=block_k,
+                          causal=causal, sm_scale=sm_scale,
+                          kv_valid=kv_valid, fresh_rows=fresh_rows),
+        grid=(b * h, sq // block_q, sk // block_k),
+        in_specs=in_specs,
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
+        scratch_shapes=[pltpu.VMEM((block_q, 1), jnp.float32),
+                        pltpu.VMEM((block_q, 1), jnp.float32),
+                        pltpu.VMEM((block_q, d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+    )(*operands)
+    return out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
 
 
 @functools.partial(
@@ -89,38 +180,40 @@ def flash_attention(q, k, v, *, causal: bool = False, block_q: int = 128,
     pad keys are masked out); d should be MXU-aligned (128) for peak
     throughput — zero-pad d and pass ``sm_scale`` for the original dim.
     """
-    b, sq, h, d = q.shape
-    _, sk, kv, _ = k.shape
-    assert h % kv == 0, (h, kv)
-    group = h // kv
-    assert sq % block_q == 0 and sk % block_k == 0, (sq, sk)
-    if sm_scale is None:
-        sm_scale = 1.0 / math.sqrt(d)
-    if kv_valid is None:
-        kv_valid = sk
-    assert 0 < kv_valid <= sk, (kv_valid, sk)
+    return _flash_call(q, k, v, None, causal=causal, block_q=block_q,
+                       block_k=block_k, sm_scale=sm_scale,
+                       kv_valid=kv_valid, fresh_rows=None,
+                       interpret=interpret)
 
-    # layout: fold batch*head into the grid's first axis; map each q-head
-    # to its kv head (GQA)
-    qf = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-    kf = k.transpose(0, 2, 1, 3).reshape(b * kv, sk, d)
-    vf = v.transpose(0, 2, 1, 3).reshape(b * kv, sk, d)
 
-    grid = (b * h, sq // block_q)
+@functools.partial(
+    jax.jit, static_argnames=("offset", "block_q", "block_k", "sm_scale",
+                              "kv_valid", "interpret"))
+def splice_attention(q, k_stale, v_stale, k_fresh, v_fresh, *, offset: int,
+                     block_q: int = 128, block_k: int = 128,
+                     sm_scale: float | None = None,
+                     kv_valid: int | None = None, interpret: bool = True):
+    """Attention over splice(stale, fresh @ offset), never materialized.
 
-    out = pl.pallas_call(
-        functools.partial(_attn_kernel, block_k=block_k, causal=causal,
-                          sm_scale=sm_scale, seq_k=sk, kv_valid=kv_valid),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((None, block_q, d), lambda bh, qb: (bh, qb, 0)),
-            pl.BlockSpec((None, sk, d),
-                         lambda bh, qb: (bh // group, 0, 0)),
-            pl.BlockSpec((None, sk, d),
-                         lambda bh, qb: (bh // group, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, block_q, d), lambda bh, qb: (bh, qb, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-        interpret=interpret,
-    )(qf, kf, vf)
-    return out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
+    q: (B, Sq, H, d); k_stale/v_stale: (B, Sk, KV, d);
+    k_fresh/v_fresh: (B, L, KV, d) with offset + L <= kv_valid <= Sk.
+    Non-causal (the DiT denoise path).  Sq/Sk must be multiples of the
+    block sizes (kernels/ops.py pads and passes ``kv_valid``).
+    """
+    sk = k_stale.shape[1]
+    local_len = k_fresh.shape[1]
+    kv_valid = sk if kv_valid is None else kv_valid
+    assert 0 <= offset and offset + local_len <= kv_valid <= sk, \
+        (offset, local_len, kv_valid, sk)
+    # lay the fresh shard out on the stale stream's block grid: window
+    # row j is global row (offset // block_k) * block_k + j
+    lead = offset % block_k
+    trail = (-(lead + local_len)) % block_k
+    pad = ((0, 0), (lead, trail), (0, 0))
+    fresh = (jnp.pad(_fold_heads(k_fresh), pad),
+             jnp.pad(_fold_heads(v_fresh), pad))
+    return _flash_call(q, k_stale, v_stale, fresh, causal=False,
+                       block_q=block_q, block_k=block_k, sm_scale=sm_scale,
+                       kv_valid=kv_valid,
+                       fresh_rows=(offset, offset + local_len),
+                       interpret=interpret)

@@ -1,19 +1,17 @@
 """jit'd public wrappers for the Pallas kernels.
 
-``use_pallas`` selects between the kernel (TPU target; interpret-mode on
-CPU) and the jnp reference path — model code calls these so the kernel is
-a drop-in layer, not a fork of the model.  Wrappers pad non-block-aligned
-sequence lengths AND head dims internally (mask-correct via the kernels'
-``kv_valid`` bound + an unpadded ``sm_scale``; outputs are sliced back),
-so callers never pre-pad.
+``use_pallas`` selects between the kernel and the jnp reference path —
+model code calls these so the kernel is a drop-in layer, not a fork of
+the model.  Kernels are compiled when JAX's default backend is a TPU and
+run in Pallas interpret mode on any other backend; nothing else selects
+the mode.  Wrappers pad non-block-aligned sequence lengths AND head dims
+internally (mask-correct via the kernels' ``kv_valid`` bound + an
+unpadded ``sm_scale``; outputs are sliced back), so callers never
+pre-pad.
 
-Environment overrides (CI / operator knobs, DESIGN.md §12):
-
-* ``REPRO_USE_PALLAS=1|0`` — force the kernel path on/off regardless of
-  what the caller (usually ``ModelConfig.use_pallas``) requested.
-* ``REPRO_PALLAS_INTERPRET=1|0`` — force Pallas interpret mode on/off;
-  default is interpret off-TPU, compiled on-TPU.  CI sets ``1`` so the
-  kernel leg is deterministic on CPU runners.
+``REPRO_USE_PALLAS=1|0`` forces the kernel path on/off regardless of
+what the caller (usually ``ModelConfig.use_pallas``) requested
+(DESIGN.md §12).
 """
 from __future__ import annotations
 
@@ -27,8 +25,8 @@ import jax.numpy as jnp
 
 from repro.kernels import ref
 from repro.kernels.adaln import adaln_modulate
-from repro.kernels.flash_attention import flash_attention
-from repro.kernels.splice import splice_attention as _splice_kernel
+from repro.kernels.flash_attention import (flash_attention,
+                                           splice_attention as _splice_kernel)
 from repro.kernels.ssd import ssd_scan
 
 _TRUTHY = ("1", "true", "yes", "on")
@@ -46,12 +44,9 @@ def use_pallas_enabled(flag: bool) -> bool:
     return v.strip().lower() in _TRUTHY
 
 
-def _interpret() -> bool:
-    """Interpret-mode selection (``REPRO_PALLAS_INTERPRET`` override)."""
-    v = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if v is None:
-        return not _on_tpu()
-    return v.strip().lower() in _TRUTHY
+def interpret_mode() -> bool:
+    """Pallas interpret mode exactly when the backend is not a TPU."""
+    return not _on_tpu()
 
 
 @dataclasses.dataclass
@@ -100,7 +95,7 @@ def attention(q, k, v, *, causal: bool = False,
     qp, kp, vp, sq, sk, d = _pad_qkv(q, k, v)
     out = flash_attention(qp, kp, vp, causal=causal,
                           sm_scale=1.0 / math.sqrt(d), kv_valid=sk,
-                          interpret=_interpret())
+                          interpret=interpret_mode())
     return out[:, :sq, :, :d]
 
 
@@ -108,9 +103,10 @@ def splice_attention(q, k_stale, v_stale, k_fresh, v_fresh, *, offset: int,
                      use_pallas: bool = False):
     """§11 hit-path attention over splice(stale, fresh @ offset).
 
-    The Pallas path streams the stale snapshot and patches the fresh
-    shard in-register (kernels/splice.py) — the concatenated KV never
-    hits HBM; the ref path materializes it (the jnp oracle).
+    The Pallas path streams the stale snapshot and the block-aligned
+    fresh shard side by side and selects rows per tile
+    (kernels/flash_attention.py) — the concatenated KV is never written;
+    the ref path materializes it (the jnp oracle).
     """
     if not use_pallas_enabled(use_pallas):
         return ref.splice_attention_ref(q, k_stale, v_stale,
@@ -122,7 +118,7 @@ def splice_attention(q, k_stale, v_stale, k_fresh, v_fresh, *, offset: int,
         v_fresh = jnp.pad(v_fresh, ((0, 0), (0, 0), (0, 0), (0, pd)))
     out = _splice_kernel(qp, kp, vp, k_fresh, v_fresh, offset=int(offset),
                          sm_scale=1.0 / math.sqrt(d), kv_valid=sk,
-                         interpret=_interpret())
+                         interpret=interpret_mode())
     return out[:, :sq, :, :d]
 
 
@@ -144,10 +140,10 @@ def fused_adaln(x, shift=None, scale=None, gate=None, residual=None, *,
         if residual is not None:
             residual = jnp.pad(residual, ((0, 0), (0, pad), (0, 0)))
         out = adaln_modulate(x, shift, scale, gate, residual, ln=ln,
-                             interpret=_interpret())
+                             interpret=interpret_mode())
         return out[:, :n]
     return adaln_modulate(x, shift, scale, gate, residual, ln=ln,
-                          interpret=_interpret())
+                          interpret=interpret_mode())
 
 
 def ssd(x, dt, A, B, C, *, chunk: int = 128, use_pallas: bool = False):
@@ -161,6 +157,6 @@ def ssd(x, dt, A, B, C, *, chunk: int = 128, use_pallas: bool = False):
         B = jnp.pad(B, ((0, 0), (0, pad), (0, 0)))
         C = jnp.pad(C, ((0, 0), (0, pad), (0, 0)))
         y, state = ssd_scan(x, dt, A, B, C, chunk=chunk,
-                            interpret=_interpret())
+                            interpret=interpret_mode())
         return y[:, :l], state
-    return ssd_scan(x, dt, A, B, C, chunk=chunk, interpret=_interpret())
+    return ssd_scan(x, dt, A, B, C, chunk=chunk, interpret=interpret_mode())

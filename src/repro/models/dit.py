@@ -130,14 +130,20 @@ def init(key, cfg: ModelConfig):
     d = cfg.d_model
     patch_in = dc.patch_size * dc.patch_size * dc.in_channels
     ks = jax.random.split(key, 8)
-    blocks = [dit_block_init(jax.random.fold_in(ks[0], i), cfg)
-              for i in range(cfg.num_layers)]
+    # one vmapped init builds the stacked layers in place: the same
+    # values as initializing layer by layer and stacking, at half the
+    # peak memory (the per-layer copies never coexist with the stack)
+    blocks = jax.vmap(
+        lambda i: dit_block_init(jax.random.fold_in(ks[0], i), cfg))(
+        jnp.arange(cfg.num_layers))
+    blocks = jax.tree.map(lambda p: ParamSpec(p.value, ("layers",) + p.axes),
+                          blocks, is_leaf=L.is_param_spec)
     return {
         "x_embed": pspec(ks[1], (patch_in, d), (None, "embed")),
         "t_mlp1": pspec(ks[2], (256, d), (None, "embed")),
         "t_mlp2": pspec(ks[3], (d, d), ("embed", "embed")),
         "txt_proj": pspec(ks[4], (dc.cond_dim, d), (None, "embed")),
-        "blocks": L.stack_layer_params(blocks),
+        "blocks": blocks,
         "final_ada_w": pzeros((d, 2 * d), ("embed", "mlp")),
         "final_ada_b": pzeros((2 * d,), (None,)),
         "final_out": pzeros((d, patch_in), ("embed", None)),

@@ -6,6 +6,8 @@ than stubbing it — it is the "encode" trajectory task.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -39,8 +41,11 @@ def init(key, cfg: ModelConfig):
     }
 
 
+@functools.partial(jax.jit, static_argnames=("cfg", "dtype"))
 def encode(params, tokens, cfg: ModelConfig, dtype=jnp.bfloat16):
-    """tokens: (B, Lt) -> embeddings (B, Lt, cond_dim)."""
+    """tokens: (B, Lt) -> embeddings (B, Lt, cond_dim).  Jitted: the
+    layer scan's body is a fresh closure per call, so run eagerly it
+    would compile again for every request."""
     x = L.embed(params["embed"], tokens, cfg, dtype)
     positions = jnp.arange(x.shape[1])[None, :]
 

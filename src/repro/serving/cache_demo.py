@@ -98,7 +98,7 @@ def cache_modes(events: list[dict]) -> list[tuple]:
             if e["ev"] == "dispatch" and e["kind"] == "denoise"]
 
 
-def _liven(pipeline, seed: int = 123, scale: float = 0.05):
+def liven(pipeline, seed: int = 123, scale: float = 0.05):
     """Replace the adaLN-Zero zero-init gates (and the zero output head)
     with small fixed-seed values.  An untrained DiT gates its attention
     output by exactly zero, so stale-KV reuse would be vacuously exact —
@@ -119,7 +119,7 @@ def _liven(pipeline, seed: int = 123, scale: float = 0.05):
 def run_wall(cfg, reqs, *, cache_interval, shift: bool = True) -> dict:
     eng = ServingEngine(cfg, CacheScriptPolicy(shift=shift), NUM_RANKS,
                         cost=CostModel(), cache_interval=cache_interval)
-    _liven(eng.pipeline)
+    liven(eng.pipeline)
     metrics = eng.serve(reqs, timeout=240)
     out = {
         "metrics": metrics,

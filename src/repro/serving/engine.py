@@ -31,7 +31,7 @@ class ServingEngine:
                  cache_interval: Optional[int] = None,
                  injector=None, snapshot_interval: Optional[int] = None,
                  snapshot_dir=None, failure_recovery: bool = True,
-                 telemetry=None):
+                 telemetry=None, pipeline: Optional[DiTPipeline] = None):
         # `num_ranks` accepts a bare rank count (back-compat: synthesizes
         # a one-host topology) or a ClusterTopology (DESIGN.md §10);
         # spanning GFC groups then run hierarchical collectives.
@@ -39,10 +39,15 @@ class ServingEngine:
         # (DESIGN.md §11): denoise steps reuse stale remote KV shards
         # for up to interval-1 steps between full refresh gathers
         # (interval=1 refreshes every step — bit-exact outputs).
+        # `pipeline` serves with an existing pipeline's weights (same
+        # cfg) instead of initializing new ones from `seed`.
         topo = as_topology(num_ranks)
         self.cfg = cfg
         self.topology = topo
-        self.pipeline = DiTPipeline(cfg, seed=seed)
+        if pipeline is None:
+            pipeline = DiTPipeline(cfg, seed=seed)
+        assert pipeline.cfg == cfg, "pipeline was built for another cfg"
+        self.pipeline = pipeline
         self.comm = GroupFreeComm(topo.num_ranks, topology=topo)
         # telemetry plane (DESIGN.md §15): one instance observes the
         # whole stack — control plane decisions/timelines, GFC

@@ -14,6 +14,7 @@ REPO = Path(__file__).resolve().parents[1]
 def _run_cell(arch, shape, mesh="2,2"):
     env = dict(os.environ)
     env["PYTHONPATH"] = str(REPO / "src")
+    env["JAX_PLATFORMS"] = "cpu"       # forced host devices, never a chip
     env["REPRO_DEVICE_COUNT"] = "4"
     env["REPRO_DRYRUN_MESH"] = mesh
     proc = subprocess.run(

@@ -56,6 +56,7 @@ print(json.dumps(out))
 def test_grouped_and_cache():
     env = dict(os.environ)
     env["PYTHONPATH"] = str(REPO / "src")
+    env["JAX_PLATFORMS"] = "cpu"       # forced host devices, never a chip
     proc = subprocess.run([sys.executable, "-c", _CHILD], env=env,
                           capture_output=True, text=True, timeout=600)
     assert proc.returncode == 0, proc.stderr[-2000:]

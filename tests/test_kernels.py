@@ -188,13 +188,20 @@ def test_env_override_numerics(monkeypatch):
     v = jax.random.normal(ks[2], (1, 100, 2, 48))
     want = ref.attention_ref(q, k, v, causal=False)
     monkeypatch.setenv("REPRO_USE_PALLAS", "1")
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
     out = ops.attention(q, k, v, causal=False, use_pallas=False)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                atol=1e-5, rtol=1e-5)
     monkeypatch.setenv("REPRO_USE_PALLAS", "0")
     out = ops.attention(q, k, v, causal=False, use_pallas=True)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+
+
+def test_interpret_mode_follows_backend(monkeypatch):
+    """Kernels compile on a TPU backend and interpret everywhere else."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    assert not ops.interpret_mode()
+    monkeypatch.setattr(ops, "_on_tpu", lambda: False)
+    assert ops.interpret_mode()
 
 
 def test_kernel_matches_model_ssd_path():

@@ -109,3 +109,17 @@ def test_gfc_descriptor_count_grows_with_layout_churn(cfg):
     eng.shutdown()
     assert regs >= 4
     assert per_reg_us < 1000.0      # paper: ~60 us
+
+
+def test_every_rank_shares_the_one_device():
+    """Ranks wrap over the local devices: with one device every rank
+    computes on it, and the weights are placed there once."""
+    import jax
+    from repro.core.executor import rank_device
+    from repro.diffusion.pipeline import DiTPipeline
+    (dev,) = jax.local_devices()
+    assert {rank_device(r) for r in range(8)} == {dev}
+    pipe = DiTPipeline(DIT_IMAGE.reduced())
+    placed = pipe.weights(0)
+    assert all(pipe.weights(r) is placed for r in range(4))
+    assert all(dev in leaf.devices() for leaf in jax.tree.leaves(placed))

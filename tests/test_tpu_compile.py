@@ -1,0 +1,92 @@
+"""Ahead-of-time compiles of the served path's Pallas kernels for a TPU
+v5e, at dit-image's served shapes (fp32, 24 heads x 64 padded to 128
+lanes by ``ops._pad_qkv``, d_model 1536), with no chip attached.
+
+Interpret mode hides what the chip's compiler refuses (VMEM overflow,
+block shapes the TPU lowering rejects, in-kernel gathers); these compile
+with the TPU compiler that ships with JAX.  The topology is described in
+a module fixture, never at import, so every xdist worker collects the
+same tests and only the worker running this file loads the TPU library.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.compile_cache import CHECKOUT, compile_cache_dir
+from repro.kernels import ops
+
+HEADS, HEAD_DIM, D_MODEL = 24, 64, 1536
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:   # noqa: BLE001 - no TPU compiler here
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        # a compile for a described chip cannot be read back from the
+        # persistent cache here, so keep it out of the cache
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        yield SingleDeviceSharding(topo.devices[0])
+        jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture
+def compiled_kernels(monkeypatch):
+    """Steer the wrappers onto the compiled (non-interpret) kernels, as
+    on a TPU backend."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+
+
+def _compile(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=sharding)
+            for s in shapes]
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in hlo      # the Pallas kernel is in the program
+
+
+@pytest.mark.parametrize("n_q,n_kv", [
+    (4096, 4096),       # 1024 px, SP degree 1
+    (9216, 9216),       # 1536 px, SP degree 1
+    (1024, 4096),       # 1024 px query shard at SP degree 4
+])
+def test_flash_attention_compiles(one_chip, compiled_kernels, n_q, n_kv):
+    _compile(lambda q, k, v: ops.attention(q, k, v, use_pallas=True),
+             one_chip, (1, n_q, HEADS, HEAD_DIM), (1, n_kv, HEADS, HEAD_DIM),
+             (1, n_kv, HEADS, HEAD_DIM))
+
+
+@pytest.mark.parametrize("batch", [1, 2])    # B=2: batched CFG and packs
+def test_adaln_full_fusion_compiles(one_chip, compiled_kernels, batch):
+    n = 4096
+    _compile(lambda x, sh, sc, g, r: ops.fused_adaln(x, sh, sc, g, r,
+                                                     use_pallas=True),
+             one_chip, (batch, n, D_MODEL), (batch, D_MODEL),
+             (batch, D_MODEL), (batch, D_MODEL), (batch, n, D_MODEL))
+
+
+@pytest.mark.parametrize("degree", [2, 4])
+def test_splice_attention_compiles(one_chip, compiled_kernels, degree):
+    n = 4096
+    local = n // degree
+    kv = (1, n, HEADS, HEAD_DIM)
+    fresh = (1, local, HEADS, HEAD_DIM)
+    _compile(lambda q, ks, vs, kf, vf: ops.splice_attention(
+        q, ks, vs, kf, vf, offset=local, use_pallas=True),
+        one_chip, fresh, kv, kv, fresh, fresh)
+
+
+def test_compile_cache_dir_from_environment():
+    """The cache goes where JAX_COMPILATION_CACHE_DIR says; unset, to a
+    fixed directory in the checkout."""
+    assert compile_cache_dir({"JAX_COMPILATION_CACHE_DIR": "/x"}) is None
+    assert compile_cache_dir({}) == str(CHECKOUT / ".jax_cache")
+    assert compile_cache_dir({}) == compile_cache_dir({})
