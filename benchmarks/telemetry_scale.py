@@ -59,7 +59,7 @@ def _retained_events(tel) -> int:
     """Raw events held in the instrument's in-memory streams."""
     return (sum(len(s) for s in tel.lifecycle.values())
             + sum(len(s) for s in tel.rank_states.values())
-            + sum(len(s) for s in tel.overlay.values())
+            + len(tel.overlay)
             + len(tel.decisions) + len(tel.cost_stream)
             + len(tel.alerts))
 

@@ -114,10 +114,10 @@ class EventLoop:
     def run(self, until: float = math.inf, max_events: int = 10 ** 7):
         plane, clock = self.plane, self.clock
         backend = plane.backend
-        # loop-health counters (DESIGN.md §15): clock-DEPENDENT by
+        # loop-health telemetry (DESIGN.md §15) is clock-DEPENDENT by
         # construction — the wall clock polls through many more
-        # iterations than the virtual clock jumps — so they live in the
-        # counter stream, never in the identity projection
+        # iterations than the virtual clock jumps — so it lives in the
+        # counter stream and regions, never in the identity projection
         tel = getattr(plane, "telemetry", None)
         for _ in range(max_events):
             plane.now = max(plane.now, clock.now())
@@ -131,13 +131,16 @@ class EventLoop:
             # wait no further than the next timed event — an arrival OR a
             # scripted failure (DESIGN.md §13): the virtual clock jumps to
             # it, the wall clock bounds its idle pause by it
-            completions = clock.wait(backend, plane.next_timed())
-            if completions is None:
-                break                   # event sources exhausted
-            if tel is not None:
-                tel.counter("loop_iterations")
+            if tel is None:
+                completions = clock.wait(backend, plane.next_timed())
+            else:
+                with tel.region("gfdit.loop.wait") as late:
+                    completions = clock.wait(backend, plane.next_timed())
+                    late["completions"] = len(completions or ())
                 if completions:
                     tel.counter("completions", len(completions))
+            if completions is None:
+                break                   # event sources exhausted
             for c in completions:
                 plane.on_completion(c)
         return plane

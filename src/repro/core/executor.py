@@ -23,7 +23,7 @@ from repro.core.migration import (execute_migration, layout_moved,
                                   plan_migration)
 from repro.core.scheduler import Completion
 from repro.core.trajectory import (ExecutionLayout, RequestGraph,
-                                   TrajectoryTask)
+                                   TrajectoryTask, id_number)
 
 
 def rank_device(rank: int):
@@ -80,6 +80,11 @@ class ThreadBackend:
     def attach(self, plane):
         self.plane = plane
 
+    @property
+    def telemetry(self):
+        plane = getattr(self, "plane", None)
+        return None if plane is None else plane.telemetry
+
     # ------------------------------------------------------------------
     def _worker(self, rank: int):
         with jax.default_device(rank_device(rank)):
@@ -97,8 +102,7 @@ class ThreadBackend:
             task, layout, graph, t_dispatch, desc, seq = job
             err, failed = None, ()
             try:
-                self.adapter.execute(task, layout, rank, self.comm, graph,
-                                     desc)
+                self._execute(rank, task, layout, graph, desc, seq)
             except CollectiveTimeout as e:
                 failed = tuple(e.missing_ranks) or (rank,)
                 self.timeouts.append(
@@ -112,8 +116,7 @@ class ThreadBackend:
     def _run_pack(self, rank: int, job: _PackJob):
         err, failed = None, ()
         try:
-            self.adapter.execute_packed(job.members, job.layout, rank,
-                                        self.comm, job.desc)
+            self._execute_pack(rank, job)
         except CollectiveTimeout as e:
             failed = tuple(e.missing_ranks) or (rank,)
             self.timeouts.append(
@@ -124,6 +127,37 @@ class ThreadBackend:
                                + traceback.format_exc())
         # pack ids are fresh per dispatch, so the pending key needs no seq
         self._finish(job.pack_id, 0, job.layout, job.t_dispatch, err, failed)
+
+    def _execute(self, rank: int, task: TrajectoryTask, layout, graph,
+                 desc, seq: int):
+        """This rank's share of one task, as a ``gfdit.task.<kind>``
+        region when telemetry is on (closed before the completion is
+        queued)."""
+        tel = self.telemetry
+        if tel is None:
+            self.adapter.execute(task, layout, rank, self.comm, graph, desc)
+            return
+        guided = graph.request.guidance is not None and layout.cfg == 1
+        with tel.region(f"gfdit.task.{task.kind}", task=id_number(task.id),
+                        seq=seq, step=task.step_index,
+                        tokens=task.meta["tokens"], rows=2 if guided else 1,
+                        degree=layout.degree, rank=rank):
+            self.adapter.execute(task, layout, rank, self.comm, graph, desc)
+
+    def _execute_pack(self, rank: int, job: _PackJob):
+        """This rank's share of a pack, as a ``gfdit.task.denoise`` region
+        (one row per member) when telemetry is on."""
+        tel = self.telemetry
+        if tel is None:
+            self.adapter.execute_packed(job.members, job.layout, rank,
+                                        self.comm, job.desc)
+            return
+        with tel.region("gfdit.task.denoise", task=id_number(job.pack_id),
+                        seq=0, tokens=job.members[0][0].meta["tokens"],
+                        rows=len(job.members), degree=job.layout.degree,
+                        rank=rank):
+            self.adapter.execute_packed(job.members, job.layout, rank,
+                                        self.comm, job.desc)
 
     def _finish(self, key_id: str, seq: int, layout, t_dispatch: float,
                 err: Optional[str], failed: tuple = ()):
@@ -170,30 +204,18 @@ class ThreadBackend:
         cache's plane-stamped effects (DESIGN.md §11) — migrate the warm
         snapshot on a same-degree layout change, or re-home/allocate the
         snapshot slots a refresh gather will fill."""
-        tel = getattr(self.plane, "telemetry", None) \
-            if hasattr(self, "plane") else None
         for aid in task.inputs:
             art = graph.artifacts[aid]
             if art.data is not None and \
                     layout_moved(art.layout, layout):
-                t0 = time.monotonic()
-                entries = plan_migration(art.fields, art.layout, layout)
-                execute_migration(self.comm, art, layout, entries)
-                if tel is not None:
-                    tel.span(layout.ranks[0], t0, time.monotonic(),
-                             "migrate", art.nbytes)
+                self._migrate("gfdit.migrate", art, layout)
         stamp = task.meta.get("cache")
         if stamp is not None:
             cart = graph.artifacts[stamp["art"]]
             if stamp["migrate"] and cart.data is not None and \
                     cart.layout is not None and \
                     cart.layout.ranks != layout.ranks:
-                t0 = time.monotonic()
-                entries = plan_migration(cart.fields, cart.layout, layout)
-                execute_migration(self.comm, cart, layout, entries)
-                if tel is not None:
-                    tel.span(layout.ranks[0], t0, time.monotonic(),
-                             "migrate-cache", cart.nbytes)
+                self._migrate("gfdit.migrate_cache", cart, layout)
             if cart.data is None:
                 cart.data = {}
             for r in layout.ranks:
@@ -205,8 +227,28 @@ class ThreadBackend:
             if art.data is None:
                 art.data = {r: {} for r in layout.ranks}
 
+    def _migrate(self, name: str, art, layout: ExecutionLayout):
+        """Move ``art`` onto ``layout`` (§5.3), as region ``name``."""
+        entries = plan_migration(art.fields, art.layout, layout)
+        tel = self.telemetry
+        if tel is None:
+            execute_migration(self.comm, art, layout, entries)
+            return
+        with tel.region(name, rank=layout.ranks[0], bytes=art.nbytes):
+            execute_migration(self.comm, art, layout, entries)
+
     def dispatch(self, task: TrajectoryTask, layout: ExecutionLayout,
                  graph: RequestGraph, now: float):
+        tel = self.telemetry
+        if tel is None:
+            return self._dispatch(task, layout, graph)
+        with tel.region("gfdit.exec.dispatch", task=id_number(task.id),
+                        seq=task.meta.get("_seq", 0),
+                        degree=layout.degree):
+            self._dispatch(task, layout, graph)
+
+    def _dispatch(self, task: TrajectoryTask, layout: ExecutionLayout,
+                  graph: RequestGraph):
         if not hasattr(self, "t0"):
             self.t0 = time.monotonic()
         self._prepare_task(task, layout, graph)
@@ -232,6 +274,15 @@ class ThreadBackend:
         rank of the shared layout; the adapter runs them as one stacked
         model call and the single completion (keyed by ``pack_id``) fans
         out in the control plane (DESIGN.md §9)."""
+        tel = self.telemetry
+        if tel is None:
+            return self._dispatch_pack(pack_id, members, layout)
+        with tel.region("gfdit.exec.dispatch", task=id_number(pack_id),
+                        seq=0, degree=layout.degree):
+            self._dispatch_pack(pack_id, members, layout)
+
+    def _dispatch_pack(self, pack_id: str, members,
+                       layout: ExecutionLayout):
         if not hasattr(self, "t0"):
             self.t0 = time.monotonic()
         for task, graph in members:

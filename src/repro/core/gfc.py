@@ -168,7 +168,7 @@ class GroupFreeComm:
                       "hierarchical": 0}
         # telemetry plane (DESIGN.md §15): set by the serving engine (or
         # a benchmark) to collect per-registration latency samples and
-        # the wall collective-overlay spans.  Instruments only APPEND to
+        # the gfdit.gfc.* collective regions.  Instruments only APPEND to
         # telemetry lists — GIL-atomic, safe from worker threads (the
         # hierarchical planner registers sub-groups under `_cv`).
         self.telemetry = None
@@ -407,17 +407,19 @@ class GroupFreeComm:
     # ------------------------------------------------------------------
     def _timed(self, op: str, desc: GroupDescriptor, rank: int,
                fn, *args):
-        """Wall collective-overlay instrument (DESIGN.md §15): times one
-        rank's passage through a collective in absolute monotonic time.
-        Disabled path is one None check — no lambda, no timestamp."""
+        """One rank's passage through a collective as a
+        ``gfdit.gfc.<op>`` region (DESIGN.md §15), with the bytes it
+        received.  Disabled path is one None check — no lambda, no
+        timestamp."""
         tel = self.telemetry
         if tel is None:
             return fn(*args)
-        t0 = time.monotonic()
-        try:
-            return fn(*args)
-        finally:
-            tel.span(rank, t0, time.monotonic(), op, desc.size)
+        with tel.region(f"gfdit.gfc.{op}", rank=rank,
+                        group=desc.size) as late:
+            out = fn(*args)
+            late["bytes"] = sum(getattr(a, "nbytes", 0) for a in out) \
+                if isinstance(out, list) else getattr(out, "nbytes", 0)
+            return out
 
     def all_gather(self, desc: GroupDescriptor, rank: int,
                    shard: np.ndarray, axis: int = 0) -> np.ndarray:

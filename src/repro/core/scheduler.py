@@ -50,7 +50,7 @@ from repro.core.event_loop import EventLoop, VirtualClock
 from repro.core.migration import layout_moved
 from repro.core.trajectory import (ClusterTopology, ExecutionLayout,
                                    Request, RequestGraph, TrajectoryTask,
-                                   as_topology)
+                                   as_topology, id_number)
 from repro.diffusion.feature_cache import CacheEntry, FeatureCachePlane
 
 
@@ -635,11 +635,13 @@ class ControlPlane:
         return False
 
     # ------------------------------------------------------------------
-    def _autodispatch_pinned(self):
+    def _autodispatch_pinned(self) -> int:
         """Honor reallocation pins at trajectory boundaries: when a pinned
         request's next denoise task is ready and the pinned ranks are
         free, the control plane dispatches it itself (migration to the
-        new layout happens in the backend's dispatch path)."""
+        new layout happens in the backend's dispatch path).  Returns the
+        number dispatched."""
+        dispatched = 0
         for rid in sorted(self.pinned):
             layout = self.pinned[rid]
             req = self.requests.get(rid)
@@ -651,23 +653,34 @@ class ControlPlane:
                     continue
                 if all(r in self.free_ranks for r in layout.ranks):
                     self._dispatch(t, layout, g, via_pin=True)
+                    dispatched += 1
                 break       # denoise steps form a chain: at most one ready
+        return dispatched
 
     # ------------------------------------------------------------------
     def schedule_point(self):
         """Invoke the policy and apply its actions.  Called by the event
         loop after every arrival, completion, preempt-requeue, and
         reallocation boundary."""
-        if self.telemetry is not None:
+        tel = self.telemetry
+        if tel is None:
+            self._schedule_point()
+            return
+        with tel.region("gfdit.plane.schedule") as late:
             # staged explanations live one schedule point: anything the
             # plane rejected must not leak onto a later application
-            self.telemetry.begin_schedule()
-        self._autodispatch_pinned()
+            tel.begin_schedule()
+            late["ready"], late["actions"] = self._schedule_point()
+
+    def _schedule_point(self) -> tuple[int, int]:
+        """(ready tasks, actions applied, pinned dispatches included)."""
+        applied = self._autodispatch_pinned()
         view = self._view()
         if not view.ready and not view.running:
-            return
+            return 0, applied
         for action in self.policy.schedule(view):
-            self.apply(action, view)
+            applied += self.apply(action, view)
+        return len(view.ready), applied
 
     # ------------------------------------------------------------------
     def _discard_outputs(self, task: TrajectoryTask, graph: RequestGraph):
@@ -678,6 +691,18 @@ class ControlPlane:
             art.data = None
 
     def on_completion(self, c: Completion):
+        tel = self.telemetry
+        if tel is None:
+            self._on_completion(c)
+            return
+        # wait_us: how long the finished task sat before the plane
+        # handled it
+        with tel.region("gfdit.plane.complete", task=id_number(c.task_id),
+                        seq=c.seq,
+                        wait_us=tel.since_us(c.finish_time, self.now)):
+            self._on_completion(c)
+
+    def _on_completion(self, c: Completion):
         if c.task_id in self.packs:
             return self._on_pack_completion(c)
         self._complete_task(c)

@@ -136,7 +136,6 @@ class SimBackend:
         if self.jitter:
             dur *= 1.0 + self.jitter * (self._rand() - 0.5)
         # migration latency when the input artifact lives in another layout
-        bytes0 = self.migrated_bytes
         mig = self._cache_effects(task, graph, layout)
         for aid in task.inputs:
             art = graph.artifacts[aid]
@@ -151,11 +150,8 @@ class SimBackend:
         tel = getattr(self.plane, "telemetry", None)
         if tel is not None and mig > 0:
             # priced-migration counter (the sim's counterpart of the wall
-            # overlay's measured migrate spans — clock-dependent stream)
+            # overlay's measured gfdit.migrate regions)
             tel.counter("sim_migrations")
-            tel.span(layout.ranks[0], now + self.dispatch_overhead,
-                     now + self.dispatch_overhead + mig, "migrate",
-                     self.migrated_bytes - bytes0)
         finish = now + self.dispatch_overhead + mig + dur
         c = Completion(task.id, finish, dur,
                        seq=task.meta.get("_seq", 0))

@@ -31,16 +31,16 @@ Two contracts govern everything here (DESIGN.md §15):
    sequence points, so a sim run and a wall run of the same workload
    produce identical :meth:`clock_independent` projections — a second
    cross-backend gate alongside ``trace_signature``.  Clock-dependent
-   data (timestamps, prices, loop counters, the wall-only collective
-   overlay, cost accuracy) is kept in separate streams and excluded
-   from the projection: the projection drops every float, every ``t``
-   and ``task`` field (task ids are a process-global counter), every
-   ``metrics`` sub-record (the staging convention for volatile
-   numbers), and flattens pack ids to a bool.
+   data (timestamps, prices, counters, the wall overlay of the
+   program's regions, cost accuracy) is kept in separate streams and
+   excluded from the projection: the projection drops every float,
+   every ``t`` and ``task`` field (task ids are a process-global
+   counter), every ``metrics`` sub-record (the staging convention for
+   volatile numbers), and flattens pack ids to a bool.
 
 Thread-safety: the control plane drives all identity streams from the
 event-loop thread.  Wall-backend worker threads only ever *append* to
-per-stream lists (``gfc_register``, ``span``) — GIL-atomic, no locks.
+per-stream lists (``gfc_register``, ``region``) — GIL-atomic, no locks.
 Sink fan-out (which worker threads can also reach) is serialized by a
 re-entrant lock.
 
@@ -60,10 +60,14 @@ byte-identical to the §15 instrument.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import logging
 import threading
+import time
 from typing import Optional
+
+import jax
 
 from repro.core.telemetry_sinks import RollupSink
 
@@ -81,9 +85,9 @@ def _raw_info(info: dict) -> dict:
     out["kind_"] = out.pop("kind")
     return out
 
-#: rank states (DESIGN.md §15 taxonomy).  ``collective`` appears only in
-#: the wall backend's overlay stream (the simulator never enters GFC),
-#: which is excluded from the identity projection by construction.
+#: rank states (DESIGN.md §15 taxonomy).  ``collective`` time shows only
+#: in the wall overlay, as ``gfdit.gfc.*`` regions (the simulator never
+#: enters GFC), which is excluded from the identity projection.
 RANK_STATES = ("idle", "busy", "migrating", "collective", "dead")
 
 #: keys dropped from the identity projection (see module docstring)
@@ -140,7 +144,7 @@ class Telemetry:
         self.cost_cells: dict[str, dict] = {}
         self.counters: dict[str, int] = {}
         self.gfc_register_s: list[float] = []    # worker-thread appends
-        self.overlay: dict[int, list] = {}       # r -> [(t, dur, op, size)]
+        self.overlay: list[tuple] = []           # (name, t, t_end, stats)
         # §16 streaming: sinks + sampling governor + alert stream
         self.sampling = sampling
         self._sampled = sampling is not None and not sampling.full
@@ -380,7 +384,7 @@ class Telemetry:
                            "observed": observed, "rel_err": rel}, kept)
 
     # ------------------------------------------------------------------
-    # counters + wall overlays (clock-dependent)
+    # counters + regions (clock-dependent)
     # ------------------------------------------------------------------
     def counter(self, name: str, inc: int = 1):
         self.counters[name] = self.counters.get(name, 0) + inc
@@ -397,21 +401,49 @@ class Telemetry:
             self._fan_out({"kind": "gfc", "t": self._t_last,
                            "s": seconds}, True)
 
-    def span(self, rank: int, t_start: float, t_end: float, op: str,
-             size: int = 0):
-        """Wall-only overlay: a collective / p2p / migration interval in
-        absolute monotonic time (re-anchored to ``t0`` when set)."""
-        base = self.t0 or 0.0
+    @contextlib.contextmanager
+    def region(self, name: str, **stats):
+        """One span of the program's work, from any thread.  It enters
+        ``jax.profiler.TraceAnnotation(name, **stats)``, so a profiler
+        trace holds it on the clock of the device's ops, and records
+        ``(name, start, end, stats)`` in the wall overlay, re-anchored
+        to ``t0`` (with no anchor, as in the simulator, only the
+        profiler gets it).  Stats are numbers, the only kind the
+        profiler's reduction keeps; the body adds those it learns late
+        to the dict the region yields."""
+        late: dict = {}
+        t_start = time.monotonic()
+        with jax.profiler.TraceAnnotation(name, **stats) as ann:
+            try:
+                yield late
+            finally:
+                if late:
+                    ann.set_metadata(**late)
+                    stats.update(late)
+                self._overlay(name, t_start, time.monotonic(), stats)
+
+    def _overlay(self, name: str, t_start: float, t_end: float,
+                 stats: dict):
+        if self.t0 is None:
+            return
+        t = t_start - self.t0
         kept = True
         if self._sampled:
-            kept = self.sampling.keep({"kind": "span", "rank": rank})
+            kept = self.sampling.keep({"kind": "span",
+                                       "rank": stats.get("rank")})
         if kept:
-            self.overlay.setdefault(rank, []).append(
-                (t_start - base, t_end - t_start, op, size))
+            self.overlay.append((name, t, t_end - self.t0, stats))
         if self.sinks:
-            self._fan_out({"kind": "span", "t": t_start - base,
-                           "rank": rank, "dur": t_end - t_start,
-                           "op": op, "size": size}, kept)
+            self._fan_out({"kind": "span", "t": t, "dur": t_end - t_start,
+                           "op": name, **stats}, kept)
+
+    def since_us(self, t: float, plane_now: float) -> float:
+        """Microseconds from plane time ``t`` until now: on the wall
+        clock where ``t0`` anchors the instrument, else on the plane's
+        own (the simulator handles an event at its time, so 0)."""
+        now = max(plane_now, t) if self.t0 is None \
+            else time.monotonic() - self.t0
+        return (now - t) * 1e6
 
     # ------------------------------------------------------------------
     # products
@@ -545,8 +577,9 @@ class Telemetry:
     # ------------------------------------------------------------------
     def perfetto(self, path=None) -> dict:
         """Chrome/Perfetto ``trace.json``: pid = host, tid = rank, X
-        slices for busy/dead rank intervals plus the wall collective
-        overlay; the control plane gets its own process with one thread
+        slices for busy/dead rank intervals plus the wall overlay's
+        regions (on their ``rank``'s track, the control plane's without
+        one); the control plane gets its own process with one thread
         per request (lifecycle spans) and instant decision events."""
         topo = self.topology
         host_of = topo.host_of if topo is not None else (lambda r: 0)
@@ -585,13 +618,15 @@ class Telemetry:
                                "dur": max(us(t_next) - us(t), 0.0),
                                "name": name, "cat": state,
                                "args": dict(info)})
-        for r, spans in self.overlay.items():
-            for t, dur, op, size in spans:
-                events.append({"ph": "X", "pid": host_of(r), "tid": r,
-                               "ts": us(t), "dur": us(dur), "name": op,
-                               "cat": "collective",
-                               "args": {"size": size}})
         cp_pid = hosts[-1] + 1
+        for name, t, t_end, stats in self.overlay:
+            r = stats.get("rank")
+            events.append({"ph": "X",
+                           "pid": cp_pid if r is None else host_of(r),
+                           "tid": 0 if r is None else r, "ts": us(t),
+                           "dur": max(us(t_end) - us(t), 0.0),
+                           "name": name, "cat": "region",
+                           "args": dict(stats)})
         events.append({"ph": "M", "pid": cp_pid, "tid": 0,
                        "name": "process_name",
                        "args": {"name": "control-plane"}})

@@ -192,8 +192,9 @@ class SamplingPolicy:
         if kind == "counter":
             return False                # aggregable: rollups carry them
         if kind == "span":
-            # overlay spans follow the retention verdict of the rank
-            # interval they decorate (coherent with the timeline)
+            # regions follow the retention verdict of the rank interval
+            # they decorate (coherent with the timeline); those with no
+            # rank (the loop's, the plane's) are dropped
             return self._rank_open_kept.get(rec.get("rank"), False)
         return True                     # gfc / unknown: low volume
 

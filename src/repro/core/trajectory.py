@@ -24,6 +24,12 @@ def fresh_id(prefix: str) -> str:
     return f"{prefix}-{next(_ids)}"
 
 
+def id_number(id_: str) -> int:
+    """The counter of a ``<prefix>-<n>`` id (tasks, packs): the numeric
+    form profiler span stats carry."""
+    return int(id_.rpartition("-")[2])
+
+
 # ---------------------------------------------------------------------------
 # Artifacts
 # ---------------------------------------------------------------------------

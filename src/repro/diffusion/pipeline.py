@@ -8,6 +8,7 @@ the cost model.
 """
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import threading
 from typing import Any, Optional
@@ -27,6 +28,9 @@ from repro.diffusion.feature_cache import snapshot_kv
 from repro.kernels import ops
 from repro.models import dit, text_encoder, vae
 from repro.models.layers import split_params
+
+
+_NO_REGION = contextlib.nullcontext()
 
 
 def _req_seed(request_id: str) -> int:
@@ -52,6 +56,13 @@ class DiTPipeline:
         self.vae_params, _ = split_params(vae.init(ks[2], cfg, hidden=32))
         self._placed: dict = {}
         self._place_lock = threading.Lock()
+        # telemetry plane (DESIGN.md §15): set by the serving engine; the
+        # step's phases then run as gfdit.step.* regions
+        self.telemetry = None
+
+    def _region(self, name: str, **stats):
+        tel = self.telemetry
+        return _NO_REGION if tel is None else tel.region(name, **stats)
 
     def weights(self, rank: int):
         """(dit, text, vae) parameter trees on ``rank``'s device, copied
@@ -112,7 +123,6 @@ class DiTPipeline:
         view = field_view(spec, layout)
         off, size = view.slices[rank]
         n_total = spec.global_shape[0]
-        t = jnp.array(t_steps, jnp.float32)
 
         stamp = task0.meta.get("cache")
         if layout.degree == 1:
@@ -151,18 +161,24 @@ class DiTPipeline:
                     V[:, off:off + size] = np.asarray(v)
                     return jnp.asarray(K), jnp.asarray(V)
 
-        x = jnp.stack([jnp.asarray(s) for s in xs])        # (B, N_loc, pd)
-        txt = jnp.stack([jnp.asarray(s) for s in txts])    # (B, Lt, cond)
-        v = dit.forward_sp_tokens(
-            self.weights(rank)[0], x, t, txt, self.cfg, pos_offset=off,
-            n_total=n_total, kv_gather=kv_gather)
-        for i, (task, graph) in enumerate(members):
-            s_now, s_next = sig_pairs[i]
-            new_x = schedule.flow_step(jnp.asarray(xs[i]), v[i], s_now,
-                                       s_next)
-            out_art = graph.artifacts[task.outputs[0]]
-            out_art.data[rank]["latent"] = np.asarray(new_x)
-            out_art.data[rank]["sigma"] = np.float32(s_next)
+        with self._region("gfdit.step.inputs"):
+            x = jnp.stack([jnp.asarray(s) for s in xs])    # (B, N_loc, pd)
+            txt = jnp.stack([jnp.asarray(s) for s in txts])  # (B, Lt, cond)
+            t = jnp.array(t_steps, jnp.float32)
+        with self._region("gfdit.step.forward", layers=self.cfg.num_layers):
+            v = dit.forward_sp_tokens(
+                self.weights(rank)[0], x, t, txt, self.cfg, pos_offset=off,
+                n_total=n_total, kv_gather=kv_gather)
+        with self._region("gfdit.step.update"):
+            new = [schedule.flow_step(x[i], v[i], s_now, s_next)
+                   for i, (s_now, s_next) in enumerate(sig_pairs)]
+        with self._region("gfdit.step.fetch",
+                          bytes=sum(n.nbytes for n in new)):
+            for (task, graph), new_x, (_, s_next) in zip(members, new,
+                                                         sig_pairs):
+                out_art = graph.artifacts[task.outputs[0]]
+                out_art.data[rank]["latent"] = np.asarray(new_x)
+                out_art.data[rank]["sigma"] = np.float32(s_next)
 
     # ------------------------------------------------------------------
     def _encode(self, task, layout, rank, graph):
@@ -223,7 +239,6 @@ class DiTPipeline:
         step = task.meta["step"]
         sigma_now = float(sigmas[step])
         sigma_next = float(sigmas[step + 1]) if step + 1 < req.steps else 0.0
-        t = jnp.array([schedule.timestep_of_sigma(sigma_now)], jnp.float32)
 
         stamp = task.meta.get("cache")
         if layout.degree == 1:
@@ -269,13 +284,19 @@ class DiTPipeline:
                 V[:, off:off + size] = np.asarray(v)
                 return jnp.asarray(K), jnp.asarray(V)
 
-        v_shard = dit.forward_sp_tokens(
-            self.weights(rank)[0], jnp.asarray(x_shard)[None], t,
-            jnp.asarray(txt)[None], self.cfg, pos_offset=off,
-            n_total=n_total, kv_gather=kv_gather)[0]
-        new_x = schedule.flow_step(jnp.asarray(x_shard), v_shard,
-                                   sigma_now, sigma_next)
-        out_art.data[rank]["latent"] = np.asarray(new_x)
+        with self._region("gfdit.step.inputs"):
+            x = jnp.asarray(x_shard)
+            txt = jnp.asarray(txt)
+            t = jnp.array([schedule.timestep_of_sigma(sigma_now)],
+                          jnp.float32)
+        with self._region("gfdit.step.forward", layers=self.cfg.num_layers):
+            v_shard = dit.forward_sp_tokens(
+                self.weights(rank)[0], x[None], t, txt[None], self.cfg,
+                pos_offset=off, n_total=n_total, kv_gather=kv_gather)[0]
+        with self._region("gfdit.step.update"):
+            new_x = schedule.flow_step(x, v_shard, sigma_now, sigma_next)
+        with self._region("gfdit.step.fetch", bytes=new_x.nbytes):
+            out_art.data[rank]["latent"] = np.asarray(new_x)
         out_art.data[rank]["sigma"] = np.float32(sigma_next)
 
     # ------------------------------------------------------------------
@@ -322,13 +343,16 @@ class DiTPipeline:
                     K = comm.all_gather(desc, rank, np.asarray(k), axis=1)
                     V = comm.all_gather(desc, rank, np.asarray(v), axis=1)
                     return jnp.asarray(K), jnp.asarray(V)
-            x = jnp.stack([jnp.asarray(x_shard), jnp.asarray(x_shard)])
-            txt = jnp.stack([jnp.asarray(txt_c), jnp.asarray(txt_u)])
-            t = jnp.array([ts, ts], jnp.float32)
-            v = dit.forward_sp_tokens(
-                self.weights(rank)[0], x, t, txt, self.cfg, pos_offset=off,
-                n_total=n_total, kv_gather=kv_gather)
-            v_c, v_u = np.asarray(v[0]), np.asarray(v[1])
+            with self._region("gfdit.step.inputs"):
+                x = jnp.asarray(x_shard)
+                rows = jnp.stack([x, x])
+                txt = jnp.stack([jnp.asarray(txt_c), jnp.asarray(txt_u)])
+                t = jnp.array([ts, ts], jnp.float32)
+            with self._region("gfdit.step.forward",
+                              layers=self.cfg.num_layers):
+                v = dit.forward_sp_tokens(
+                    self.weights(rank)[0], rows, t, txt, self.cfg,
+                    pos_offset=off, n_total=n_total, kv_gather=kv_gather)
         else:
             b = layout.branch_of(rank)
             branch = desc.branches[b]
@@ -344,23 +368,30 @@ class DiTPipeline:
                     V = comm.all_gather(branch, rank, np.asarray(v),
                                         axis=1)
                     return jnp.asarray(K), jnp.asarray(V)
-            txt = txt_c if b == 0 else txt_u
-            t = jnp.array([ts], jnp.float32)
-            v_mine = dit.forward_sp_tokens(
-                self.weights(rank)[0], jnp.asarray(x_shard)[None], t,
-                jnp.asarray(txt)[None], self.cfg, pos_offset=off,
-                n_total=n_total, kv_gather=kv_gather)[0]
-            # the one guidance-merge exchange: branch peers sharing this
-            # token slice swap velocity shards; merge-group rank order is
-            # branch order, so parts[0]=cond, parts[1]=uncond everywhere
-            both = comm.all_gather(merge, rank,
-                                   np.asarray(v_mine)[None], axis=0)
-            v_c, v_u = both[0], both[1]
-        merged = jnp.asarray(v_u) + g * (jnp.asarray(v_c)
-                                         - jnp.asarray(v_u))
-        new_x = schedule.flow_step(jnp.asarray(x_shard), merged,
-                                   sigma_now, sigma_next)
-        out_art.data[rank]["latent"] = np.asarray(new_x)
+            with self._region("gfdit.step.inputs"):
+                x = jnp.asarray(x_shard)
+                txt = jnp.asarray(txt_c if b == 0 else txt_u)
+                t = jnp.array([ts], jnp.float32)
+            with self._region("gfdit.step.forward",
+                              layers=self.cfg.num_layers):
+                v_mine = dit.forward_sp_tokens(
+                    self.weights(rank)[0], x[None], t, txt[None], self.cfg,
+                    pos_offset=off, n_total=n_total, kv_gather=kv_gather)[0]
+        with self._region("gfdit.step.update"):
+            if layout.cfg == 1:
+                v_c, v_u = v[0], v[1]
+            else:
+                # the one guidance-merge exchange: branch peers sharing
+                # this token slice swap velocity shards; merge-group rank
+                # order is branch order, so parts[0]=cond, parts[1]=uncond
+                both = comm.all_gather(merge, rank,
+                                       np.asarray(v_mine)[None], axis=0)
+                v_c, v_u = both[0], both[1]
+            merged = jnp.asarray(v_u) + g * (jnp.asarray(v_c)
+                                             - jnp.asarray(v_u))
+            new_x = schedule.flow_step(x, merged, sigma_now, sigma_next)
+        with self._region("gfdit.step.fetch", bytes=new_x.nbytes):
+            out_art.data[rank]["latent"] = np.asarray(new_x)
         out_art.data[rank]["sigma"] = np.float32(sigma_next)
 
     # ------------------------------------------------------------------

@@ -100,4 +100,5 @@ def adaln_modulate(x, shift=None, scale=None, gate=None, residual=None, *,
         out_specs=pl.BlockSpec((None, block_n, d), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((b, n, d), x.dtype),
         interpret=interpret,
+        name="adaln_modulate",      # the op's name in a profiler trace
     )(*operands)

@@ -163,6 +163,9 @@ def _flash_call(q, k, v, fresh, *, causal, block_q, block_k, sm_scale,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        # the op's name in a profiler trace (flash_attention.N), for the
+        # splice path too, whatever wrapper calls it
+        name="flash_attention",
     )(*operands)
     return out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
 

@@ -51,8 +51,10 @@ class ServingEngine:
         self.comm = GroupFreeComm(topo.num_ranks, topology=topo)
         # telemetry plane (DESIGN.md §15): one instance observes the
         # whole stack — control plane decisions/timelines, GFC
-        # registration latency, and the worker collective overlay
+        # registration latency, and the regions of the workers' tasks,
+        # steps and collectives
         self.comm.telemetry = telemetry
+        self.pipeline.telemetry = telemetry
         self.backend = ThreadBackend(self.pipeline, topo.num_ranks,
                                      comm=self.comm)
         self.cp = ControlPlane(topo, policy, cost or CostModel(),

@@ -6,25 +6,32 @@ ids), and the enabled path's streams are well-formed — plus the
 Perfetto export shape, the GFC latency histogram, and the
 ``ControlPlane.metrics()`` edge cases (empty run, all-failed run, and
 the unfinished-counts-as-violation SLO rule the serving timeout path
-relies on).  Cross-backend telemetry identity on REAL serving runs is
-gated in tests/test_elastic_backends.py / tests/test_hybrid_shapes.py
-and benchmarks/telemetry_suite.py.
+relies on) — and, on the thread backend under the profiler, the
+program's ``gfdit.*`` regions: in the trace with their stats, nested
+as a step nests, and absent when telemetry is off.  Cross-backend
+telemetry identity on REAL serving runs is gated in
+tests/test_elastic_backends.py / tests/test_hybrid_shapes.py and
+benchmarks/telemetry_suite.py.
 """
 from __future__ import annotations
 
 import json
 import math
+from pathlib import Path
 
+import jax
 import pytest
 
 from repro.configs.dit_models import DIT_IMAGE
 from repro.core.cost_model import CostModel
 from repro.core.policies import make_policy
-from repro.core.scheduler import ControlPlane, trace_signature
+from repro.core.scheduler import (ControlPlane, Dispatch, PackedDispatch,
+                                  Policy, trace_signature)
 from repro.core.simulator import SimBackend
 from repro.core.telemetry import (RANK_STATES, Telemetry, _sanitize)
-from repro.core.trajectory import ClusterTopology, Request
+from repro.core.trajectory import ClusterTopology, ExecutionLayout, Request
 from repro.diffusion.adapters import convert_request
+from repro.serving.engine import ServingEngine
 
 CFG = DIT_IMAGE.reduced()
 TOPO = ClusterTopology(num_hosts=2, ranks_per_host=2)
@@ -280,3 +287,204 @@ def test_metrics_unfinished_counts_as_slo_violation():
     expect = 1.0 - (unfinished + done_late) / len(cp.requests)
     assert m["slo_attainment"] == pytest.approx(expect)
     assert m["failed"] == unfinished
+
+
+# ---------------------------------------------------------------------------
+# program regions on the profiler's clock (thread backend)
+# ---------------------------------------------------------------------------
+
+TASK_STATS = {"task", "seq", "step", "tokens", "rows", "degree", "rank"}
+#: every gfdit.* region a degree-2 guided + unguided run enters, with
+#: the stats it must carry
+REGION_STATS = {
+    "gfdit.loop.wait": {"completions"},
+    "gfdit.plane.schedule": {"ready", "actions"},
+    "gfdit.plane.complete": {"task", "seq", "wait_us"},
+    "gfdit.exec.dispatch": {"task", "seq", "degree"},
+    "gfdit.task.encode": TASK_STATS,
+    "gfdit.task.denoise": TASK_STATS,
+    "gfdit.task.decode": TASK_STATS,
+    "gfdit.step.inputs": set(),
+    "gfdit.step.forward": {"layers"},
+    "gfdit.step.update": set(),
+    "gfdit.step.fetch": {"bytes"},
+    "gfdit.gfc.all_gather": {"rank", "group", "bytes"},
+    "gfdit.migrate": {"rank", "bytes"},
+}
+
+
+class SpTwo(Policy):
+    """Encode and decode on one rank, denoise at SP degree 2: the
+    latent migrates onto two ranks and each step all-gathers K/V."""
+    name = "sp-two"
+
+    def schedule(self, view):
+        out, free = [], list(view.free_ranks)
+        for t, _, _ in sorted(view.ready, key=lambda x: x[0].id):
+            k = 2 if t.kind == "denoise" else 1
+            if len(free) < k:
+                break
+            out.append(Dispatch(t.id, ExecutionLayout(tuple(free[:k]))))
+            free = free[k:]
+        return out
+
+
+def _serve_two(telemetry):
+    eng = ServingEngine(CFG, SpTwo(), 2, telemetry=telemetry)
+    reqs = [Request(id=f"{name}0", model="dit-image", height=64, width=64,
+                    frames=1, steps=2, arrival=0.0, guidance=g)
+            for name, g in (("g", 4.5), ("u", None))]
+    try:
+        m = eng.serve(reqs, timeout=120.0)
+    finally:
+        eng.shutdown()
+    assert m["completed"] == 2
+    return eng
+
+
+def _profiled_regions(trace_dir) -> list:
+    """The trace's gfdit.* host events: (name, thread, start, end,
+    stats); a thread is its plane and line (one line per thread)."""
+    from jax.profiler import ProfileData
+    path = sorted(Path(trace_dir).rglob("*.xplane.pb"))[-1]
+    out = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        for i, line in enumerate(plane.lines):
+            for e in line.events:
+                if e.name.startswith("gfdit."):
+                    out.append((e.name, (plane.name, i), e.start_ns,
+                                e.start_ns + e.duration_ns, dict(e.stats)))
+    return out
+
+
+@pytest.fixture(scope="module")
+def served(tmp_path_factory):
+    tel = Telemetry()
+    trace_dir = tmp_path_factory.mktemp("profile")
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    with jax.profiler.trace(str(trace_dir), profiler_options=options):
+        _serve_two(tel)
+    return tel, _profiled_regions(trace_dir)
+
+
+def test_regions_reach_the_profiler_with_numeric_stats(served):
+    _, regions = served
+    seen: dict = {}
+    for name, _, a, b, stats in regions:
+        assert b >= a
+        assert all(isinstance(v, (int, float)) for v in stats.values())
+        seen.setdefault(name, []).append(stats)
+    assert set(REGION_STATS) <= set(seen)
+    for name, want in REGION_STATS.items():
+        for stats in seen[name]:
+            assert want <= set(stats), (name, stats)
+    assert all(s["wait_us"] >= 0 for s in seen["gfdit.plane.complete"])
+    assert {s["rows"] for s in seen["gfdit.task.denoise"]} == {1, 2}
+    assert {s["degree"] for s in seen["gfdit.task.denoise"]} == {2}
+    assert all(s["layers"] == CFG.num_layers
+               for s in seen["gfdit.step.forward"])
+    assert any(s["actions"] >= 1 for s in seen["gfdit.plane.schedule"])
+
+
+def test_step_regions_nest_inside_their_task(served):
+    _, regions = served
+    tasks = [r for r in regions if r[0] == "gfdit.task.denoise"]
+    # 2 requests x 2 steps, each on both ranks of its layout
+    assert len(tasks) == 8
+    for name in ("gfdit.step.inputs", "gfdit.step.forward",
+                 "gfdit.step.update", "gfdit.step.fetch"):
+        steps = [r for r in regions if r[0] == name]
+        assert len(steps) == len(tasks)
+        for _, thread, a, b, _ in steps:
+            assert sum(1 for _, th, ta, tb, _ in tasks
+                       if th == thread and ta <= a and b <= tb) == 1
+    # inside one task the phases run in order
+    for _, thread, ta, tb, _ in tasks:
+        order = sorted((a, name) for name, th, a, b, _ in regions
+                       if th == thread and ta <= a and b <= tb
+                       and name.startswith("gfdit.step."))
+        assert [n for _, n in order] == [
+            "gfdit.step.inputs", "gfdit.step.forward", "gfdit.step.update",
+            "gfdit.step.fetch"]
+
+
+def test_plane_completions_name_the_tasks_that_ran(served):
+    _, regions = served
+    ran = {(s["task"], s["seq"]) for name, *_, s in regions
+           if name == "gfdit.task.denoise"}
+    handled = {(s["task"], s["seq"]) for name, *_, s in regions
+               if name == "gfdit.plane.complete"}
+    assert ran and ran <= handled
+    dispatched = {(s["task"], s["seq"]) for name, *_, s in regions
+                  if name == "gfdit.exec.dispatch"}
+    assert ran <= dispatched
+
+
+def test_regions_in_overlay_and_perfetto(served):
+    tel, regions = served
+    names = [name for name, *_ in tel.overlay]
+    assert sorted(names) == sorted(name for name, *_ in regions)
+    for name, t, t_end, stats in tel.overlay:
+        assert 0.0 <= t <= t_end
+    evs = tel.perfetto()["traceEvents"]
+    cp_pid = next(e["pid"] for e in evs if e["ph"] == "M"
+                  and e["args"].get("name") == "control-plane")
+    region_x = [e for e in evs if e.get("cat") == "region"]
+    assert len(region_x) == len(tel.overlay)
+    for e in region_x:
+        rank = e["args"].get("rank")
+        assert (e["pid"], e["tid"]) == ((cp_pid, 0) if rank is None
+                                        else (0, rank))
+
+
+class PackBoth(Policy):
+    """One rank: encodes first, then both requests' denoise steps as one
+    pack, then the decodes."""
+    name = "pack-both"
+
+    def schedule(self, view):
+        if 0 not in view.free_ranks or not view.ready:
+            return []
+        ready = sorted((t for t, _, _ in view.ready),
+                       key=lambda t: (t.kind != "encode", t.id))
+        steps = [t.id for t in ready if t.kind == "denoise"]
+        one = ExecutionLayout((0,))
+        if ready[0].kind != "encode" and len(steps) == 2:
+            return [PackedDispatch(tuple(steps), one)]
+        return [Dispatch(ready[0].id, one)]
+
+
+def test_a_pack_runs_as_one_task_region():
+    tel = Telemetry()
+    eng = ServingEngine(CFG, PackBoth(), 1, telemetry=tel)
+    reqs = [Request(id=f"p{i}", model="dit-image", height=64, width=64,
+                    frames=1, steps=2, arrival=0.0) for i in range(2)]
+    try:
+        assert eng.serve(reqs, timeout=120.0)["completed"] == 2
+    finally:
+        eng.shutdown()
+    tasks = [st for name, _, _, st in tel.overlay
+             if name == "gfdit.task.denoise"]
+    assert [st["rows"] for st in tasks] == [2, 2]
+    packs = {st["task"] for st in tasks}
+    assert packs <= {st["task"] for name, _, _, st in tel.overlay
+                     if name == "gfdit.exec.dispatch"}
+    fetched = [st["bytes"] for name, _, _, st in tel.overlay
+               if name == "gfdit.step.fetch"]
+    assert len(fetched) == 2 and len(set(fetched)) == 1
+
+
+def test_no_region_is_entered_with_telemetry_off(monkeypatch):
+    made = []
+
+    class Counting(jax.profiler.TraceAnnotation):
+        def __init__(self, *args, **kwargs):
+            made.append(args[0])
+            super().__init__(*args, **kwargs)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+    _serve_two(None)
+    assert made == []
+    _serve_two(Telemetry())
+    assert made

@@ -8,6 +8,8 @@ with the TPU compiler that ships with JAX.  The topology is described in
 a module fixture, never at import, so every xdist worker collects the
 same tests and only the worker running this file loads the TPU library.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -46,11 +48,18 @@ def compiled_kernels(monkeypatch):
     monkeypatch.setattr(ops, "_on_tpu", lambda: True)
 
 
-def _compile(fn, sharding, *shapes):
+def _compile(fn, sharding, kernel, *shapes):
+    """Compile ``fn`` for the described chip; the Pallas kernel must be
+    in the program as a custom call named ``kernel`` (``kernel.N`` is
+    the op name a profiler trace gives it, which the benchmark's
+    roofline readers match)."""
     args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=sharding)
             for s in shapes]
     hlo = jax.jit(fn).lower(*args).compile().as_text()
-    assert "tpu_custom_call" in hlo      # the Pallas kernel is in the program
+    calls = re.findall(r"%([\w.-]+) = \S+ custom-call\(.*"
+                       r"custom_call_target=\"tpu_custom_call\"", hlo)
+    assert calls and all(re.fullmatch(rf"{kernel}\.\d+", c)
+                         for c in calls), calls
 
 
 @pytest.mark.parametrize("n_q,n_kv", [
@@ -60,8 +69,8 @@ def _compile(fn, sharding, *shapes):
 ])
 def test_flash_attention_compiles(one_chip, compiled_kernels, n_q, n_kv):
     _compile(lambda q, k, v: ops.attention(q, k, v, use_pallas=True),
-             one_chip, (1, n_q, HEADS, HEAD_DIM), (1, n_kv, HEADS, HEAD_DIM),
-             (1, n_kv, HEADS, HEAD_DIM))
+             one_chip, "flash_attention", (1, n_q, HEADS, HEAD_DIM),
+             (1, n_kv, HEADS, HEAD_DIM), (1, n_kv, HEADS, HEAD_DIM))
 
 
 @pytest.mark.parametrize("batch", [1, 2])    # B=2: batched CFG and packs
@@ -69,7 +78,7 @@ def test_adaln_full_fusion_compiles(one_chip, compiled_kernels, batch):
     n = 4096
     _compile(lambda x, sh, sc, g, r: ops.fused_adaln(x, sh, sc, g, r,
                                                      use_pallas=True),
-             one_chip, (batch, n, D_MODEL), (batch, D_MODEL),
+             one_chip, "adaln_modulate", (batch, n, D_MODEL), (batch, D_MODEL),
              (batch, D_MODEL), (batch, D_MODEL), (batch, n, D_MODEL))
 
 
@@ -81,7 +90,7 @@ def test_splice_attention_compiles(one_chip, compiled_kernels, degree):
     fresh = (1, local, HEADS, HEAD_DIM)
     _compile(lambda q, ks, vs, kf, vf: ops.splice_attention(
         q, ks, vs, kf, vf, offset=local, use_pallas=True),
-        one_chip, fresh, kv, kv, fresh, fresh)
+        one_chip, "flash_attention", fresh, kv, kv, fresh, fresh)
 
 
 def test_compile_cache_dir_from_environment():
