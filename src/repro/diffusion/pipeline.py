@@ -75,6 +75,22 @@ class DiTPipeline:
                     (self.dit_params, self.txt_params, self.vae_params), dev)
             return self._placed[dev]
 
+    def _forward(self, rank: int, x, t, txt, off: int, n_total: int,
+                 kv_gather):
+        """The denoiser's forward on ``rank``, as the step's
+        ``gfdit.step.forward`` region.  The region's ``builds`` stat
+        counts the layer programs the process built while it ran
+        (``dit.builds``): 0 once every shape is warm."""
+        with self._region("gfdit.step.forward",
+                          layers=self.cfg.num_layers) as late:
+            before = dit.builds()
+            v = dit.forward_sp_tokens(
+                self.weights(rank)[0], x, t, txt, self.cfg, pos_offset=off,
+                n_total=n_total, kv_gather=kv_gather)
+            if late is not None:
+                late["builds"] = dit.builds() - before
+        return v
+
     # ------------------------------------------------------------------
     # adapter interface: execute this rank's share of a trajectory task
     # ------------------------------------------------------------------
@@ -165,10 +181,7 @@ class DiTPipeline:
             x = jnp.stack([jnp.asarray(s) for s in xs])    # (B, N_loc, pd)
             txt = jnp.stack([jnp.asarray(s) for s in txts])  # (B, Lt, cond)
             t = jnp.array(t_steps, jnp.float32)
-        with self._region("gfdit.step.forward", layers=self.cfg.num_layers):
-            v = dit.forward_sp_tokens(
-                self.weights(rank)[0], x, t, txt, self.cfg, pos_offset=off,
-                n_total=n_total, kv_gather=kv_gather)
+        v = self._forward(rank, x, t, txt, off, n_total, kv_gather)
         with self._region("gfdit.step.update"):
             new = [schedule.flow_step(x[i], v[i], s_now, s_next)
                    for i, (s_now, s_next) in enumerate(sig_pairs)]
@@ -289,10 +302,8 @@ class DiTPipeline:
             txt = jnp.asarray(txt)
             t = jnp.array([schedule.timestep_of_sigma(sigma_now)],
                           jnp.float32)
-        with self._region("gfdit.step.forward", layers=self.cfg.num_layers):
-            v_shard = dit.forward_sp_tokens(
-                self.weights(rank)[0], x[None], t, txt[None], self.cfg,
-                pos_offset=off, n_total=n_total, kv_gather=kv_gather)[0]
+        v_shard = self._forward(rank, x[None], t, txt[None], off, n_total,
+                                kv_gather)[0]
         with self._region("gfdit.step.update"):
             new_x = schedule.flow_step(x, v_shard, sigma_now, sigma_next)
         with self._region("gfdit.step.fetch", bytes=new_x.nbytes):
@@ -348,11 +359,7 @@ class DiTPipeline:
                 rows = jnp.stack([x, x])
                 txt = jnp.stack([jnp.asarray(txt_c), jnp.asarray(txt_u)])
                 t = jnp.array([ts, ts], jnp.float32)
-            with self._region("gfdit.step.forward",
-                              layers=self.cfg.num_layers):
-                v = dit.forward_sp_tokens(
-                    self.weights(rank)[0], rows, t, txt, self.cfg,
-                    pos_offset=off, n_total=n_total, kv_gather=kv_gather)
+            v = self._forward(rank, rows, t, txt, off, n_total, kv_gather)
         else:
             b = layout.branch_of(rank)
             branch = desc.branches[b]
@@ -372,11 +379,8 @@ class DiTPipeline:
                 x = jnp.asarray(x_shard)
                 txt = jnp.asarray(txt_c if b == 0 else txt_u)
                 t = jnp.array([ts], jnp.float32)
-            with self._region("gfdit.step.forward",
-                              layers=self.cfg.num_layers):
-                v_mine = dit.forward_sp_tokens(
-                    self.weights(rank)[0], x[None], t, txt[None], self.cfg,
-                    pos_offset=off, n_total=n_total, kv_gather=kv_gather)[0]
+            v_mine = self._forward(rank, x[None], t, txt[None], off,
+                                   n_total, kv_gather)[0]
         with self._region("gfdit.step.update"):
             if layout.cfg == 1:
                 v_c, v_u = v[0], v[1]

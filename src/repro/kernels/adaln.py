@@ -27,6 +27,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _adaln_kernel(*refs, eps: float, ln: bool, has_mod: bool,
@@ -84,6 +85,17 @@ def adaln_modulate(x, shift=None, scale=None, gate=None, residual=None, *,
     # modulation rows as (B, 1, D): the block's last two dims then equal
     # the array's, which the TPU lowering needs at any batch size
     row = pl.BlockSpec((None, 1, d), lambda i, j: (i, 0, 0))
+    out_shape = jax.ShapeDtypeStruct((b, n, d), x.dtype)
+    if not interpret:       # the interpreter takes no memory spaces
+        # the (B, N, D) tensors stay in HBM: that is the one pass the
+        # kernel fuses, and what its roofline counts; inside a larger
+        # compiled program XLA would otherwise keep those that fit in
+        # VMEM there
+        x = pltpu.with_memory_space_constraint(x, pltpu.HBM)
+        if has_gate:
+            residual = pltpu.with_memory_space_constraint(residual,
+                                                          pltpu.HBM)
+        out_shape = pltpu.HBM(out_shape.shape, out_shape.dtype)
     operands, in_specs = [x], [tile]
     if has_mod:
         operands += [shift[:, None], scale[:, None]]
@@ -98,7 +110,7 @@ def adaln_modulate(x, shift=None, scale=None, gate=None, residual=None, *,
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((None, block_n, d), lambda i, j: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, n, d), x.dtype),
+        out_shape=out_shape,
         interpret=interpret,
         name="adaln_modulate",      # the op's name in a profiler trace
     )(*operands)

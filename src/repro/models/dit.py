@@ -10,6 +10,8 @@ Token layout: latents (B, F, H, W, C) -> patchify p x p spatial ->
 """
 from __future__ import annotations
 
+import functools
+import threading
 from typing import Any, Optional
 
 import jax
@@ -222,15 +224,129 @@ def token_count(cfg: ModelConfig, height: int, width: int,
 
 
 # ---------------------------------------------------------------------------
-# Sequence-parallel forward (paper's SP layout, executed over GFC)
+# Sequence-parallel forward: compiled layer programs split at the K/V gather
 # ---------------------------------------------------------------------------
+#
+# The served step runs as a handful of compiled programs: the head
+# (embedding, positions, timestep and text conditioning), then per layer
+# ``pre`` (modulation, norm, q/k/v projections) -> the K/V gather on the
+# host -> ``post`` (self-attention on the gathered K/V, out-projection,
+# gated residual, cross-attention, MLP), then the tail (final modulated
+# norm, out-projection).  Each program is built once per shape and serves
+# every layer: the stacked ``blocks`` weights and the layer index are
+# arguments, and the layer is sliced inside the program.  The same
+# programs run at every SP degree, packed or solo, so the degrees and the
+# packs stay bitwise equal (DESIGN.md §17).
+
+_builds = 0
+_builds_lock = threading.Lock()
+
+
+def _built():
+    """Count one build of a layer program; runs only while tracing."""
+    global _builds
+    with _builds_lock:
+        _builds += 1
+
+
+def builds() -> int:
+    """How many times this process has built (traced) a program of the
+    served step: once per segment and shape, then 0 more once warm."""
+    return _builds
+
+
+def _layer(blocks, i):
+    """Layer ``i`` of the stacked block weights, sliced in the program."""
+    return jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
+        a, i, keepdims=False), blocks)
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "n_total"))
+def _head(params, tok_shard, t, txt_embeds, pos_offset, *, cfg, n_total):
+    _built()
+    f32 = jnp.float32
+    x = jnp.einsum("bnp,pd->bnd", tok_shard.astype(f32), params["x_embed"])
+    pe = pos_embedding(n_total, cfg.d_model)
+    x = x + jax.lax.dynamic_slice_in_dim(pe, pos_offset, x.shape[1])[None]
+
+    t_emb = timestep_embedding(t, 256)
+    c = jnp.einsum("bk,kd->bd", t_emb, params["t_mlp1"])
+    c = jnp.einsum("bd,de->be", jax.nn.silu(c), params["t_mlp2"])
+    txt = jnp.einsum("blk,kd->bld", txt_embeds.astype(f32),
+                     params["txt_proj"])
+    return x, c + txt.mean(axis=1), txt
+
+
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def _pre(blocks, i, x, c, *, cfg):
+    """Layer ``i`` up to the gather: its modulation rows and q, k, v."""
+    _built()
+    p = _layer(blocks, i)
+    mods = jnp.einsum("bd,dk->bk", jax.nn.silu(c), p["ada_w"]) + p["ada_b"]
+    sh_a, sc_a, _, _, _, _ = jnp.split(mods, 6, axis=-1)
+    h = _mod_norm(x, sh_a, sc_a, up=cfg.use_pallas)
+    ap = p["attn"]
+    q = jnp.einsum("bsd,dhk->bshk", h, ap["wq"])
+    k = jnp.einsum("bsd,dhk->bshk", h, ap["wk"])
+    v = jnp.einsum("bsd,dhk->bshk", h, ap["wv"])
+    return mods, q, k, v
+
+
+def _post_rest(p, x, mods, txt, attn, cfg: ModelConfig):
+    """Layer ``p`` from its self-attention output on."""
+    up = cfg.use_pallas
+    _, _, g_a, sh_m, sc_m, g_m = jnp.split(mods, 6, axis=-1)
+    attn = jnp.einsum("bshk,hkd->bsd", attn, p["attn"]["wo"])
+    x = _gated_residual(x, g_a, attn, up=up)
+
+    h = _mod_norm(x, up=up)
+    ca, _ = L.attention_apply(p["cross"], h, cfg, causal=False, kv_x=txt,
+                              use_rope=False)
+    x = x + ca
+
+    h = _mod_norm(x, sh_m, sc_m, up=up)
+    return _gated_residual(x, g_m, L.swiglu_apply(p["mlp"], h), up=up)
+
+
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def _post(blocks, i, x, mods, txt, q, k, v, *, cfg):
+    """Layer ``i`` from the gather on: sharded queries over full K/V."""
+    _built()
+    if cfg.use_pallas:
+        attn = ops.attention(q, k, v, causal=False, use_pallas=True)
+    else:
+        attn = L.sdpa(q, k, v, causal=False)
+    return _post_rest(_layer(blocks, i), x, mods, txt, attn, cfg)
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "offset"))
+def _post_spliced(blocks, i, x, mods, txt, q, k_stale, v_stale, k_fresh,
+                  v_fresh, *, cfg, offset):
+    """``_post`` on a §11 cache hit: the splice kernel patches this
+    rank's fresh K/V into the stale snapshot's stream (the fresh rows'
+    ``offset`` lays out the kernel's blocks, so it is static)."""
+    _built()
+    attn = ops.splice_attention(q, k_stale, v_stale, k_fresh, v_fresh,
+                                offset=offset, use_pallas=True)
+    return _post_rest(_layer(blocks, i), x, mods, txt, attn, cfg)
+
+
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def _tail(params, x, c, *, cfg):
+    _built()
+    mods = jnp.einsum("bd,dk->bk", jax.nn.silu(c), params["final_ada_w"]) \
+        + params["final_ada_b"]
+    sh, sc = jnp.split(mods, 2, axis=-1)
+    x = _mod_norm(x, sh, sc, up=cfg.use_pallas)
+    return jnp.einsum("bnd,dp->bnp", x, params["final_out"])
+
 
 def forward_sp_tokens(params, tok_shard, t, txt_embeds, cfg: ModelConfig, *,
-                      pos_offset: int, n_total: int, kv_gather,
-                      dtype=jnp.float32):
-    """Denoiser forward over a TOKEN SHARD under sequence parallelism.
+                      pos_offset: int, n_total: int, kv_gather):
+    """Denoiser forward over a TOKEN SHARD under sequence parallelism,
+    in float32.
 
-    tok_shard: (1, N_local, patch_dim) — this rank's patchified tokens.
+    tok_shard: (B, N_local, patch_dim) — this rank's patchified tokens.
     kv_gather(k, v, layer) -> (K, V) gathers key/value over the token axis
     across the execution group (GFC all-gather in the thread runtime;
     identity at SP1).  Queries stay local, so compute is token-sharded
@@ -243,58 +359,25 @@ def forward_sp_tokens(params, tok_shard, t, txt_embeds, cfg: ModelConfig, *,
     and the splice happens inside the attention kernel's K/V stream —
     the concatenated tensors never materialize (DESIGN.md §12).
 
+    Between gathers the step runs compiled: head, ``pre`` and ``post``
+    per layer, tail (see above); :func:`builds` counts their builds.
+
     Returns the velocity prediction for the local token shard
-    (1, N_local, patch_dim).
+    (B, N_local, patch_dim).
     """
     up = ops.use_pallas_enabled(cfg.use_pallas)
-    x = jnp.einsum("bnp,pd->bnd", tok_shard.astype(dtype),
-                   params["x_embed"].astype(dtype))
-    pe = pos_embedding(n_total, cfg.d_model).astype(dtype)
-    x = x + pe[pos_offset:pos_offset + x.shape[1]][None]
-
-    t_emb = timestep_embedding(t, 256)
-    c = jnp.einsum("bk,kd->bd", t_emb, params["t_mlp1"].astype(dtype))
-    c = jnp.einsum("bd,de->be", jax.nn.silu(c), params["t_mlp2"].astype(dtype))
-    txt = jnp.einsum("blk,kd->bld", txt_embeds.astype(dtype),
-                     params["txt_proj"].astype(dtype))
-    c = c + txt.mean(axis=1)
-
-    n_layers = jax.tree.leaves(params["blocks"])[0].shape[0]
-    for i in range(n_layers):
-        p = jax.tree.map(lambda a: a[i], params["blocks"])
-        mods = jnp.einsum("bd,dk->bk", jax.nn.silu(c),
-                          p["ada_w"].astype(dtype)) + p["ada_b"].astype(dtype)
-        sh_a, sc_a, g_a, sh_m, sc_m, g_m = jnp.split(mods, 6, axis=-1)
-
-        h = _mod_norm(x, sh_a, sc_a, up=up)
-        ap = p["attn"]
-        q = jnp.einsum("bsd,dhk->bshk", h, ap["wq"].astype(dtype))
-        k = jnp.einsum("bsd,dhk->bshk", h, ap["wk"].astype(dtype))
-        v = jnp.einsum("bsd,dhk->bshk", h, ap["wv"].astype(dtype))
+    if cfg.use_pallas != up:
+        cfg = cfg.with_(use_pallas=up)
+    blocks = params["blocks"]
+    x, c, txt = _head(params, tok_shard, t, txt_embeds, pos_offset,
+                      cfg=cfg, n_total=n_total)
+    for i in range(jax.tree.leaves(blocks)[0].shape[0]):
+        mods, q, k, v = _pre(blocks, i, x, c, cfg=cfg)
         kv = kv_gather(k, v, i)                     # GFC all-gather (axis=1)
         if isinstance(kv, ops.SplicedKV):           # §11 hit, fused splice
-            attn = ops.splice_attention(q, kv.k_stale, kv.v_stale,
-                                        kv.k_fresh, kv.v_fresh,
-                                        offset=kv.offset, use_pallas=True)
-        elif up:                                    # sharded-Q / full-KV
-            attn = ops.attention(q, *kv, causal=False, use_pallas=True)
+            x = _post_spliced(blocks, i, x, mods, txt, q, kv.k_stale,
+                              kv.v_stale, kv.k_fresh, kv.v_fresh, cfg=cfg,
+                              offset=kv.offset)
         else:
-            K, V = kv
-            attn = L.sdpa(q, K, V, causal=False)
-        attn = jnp.einsum("bshk,hkd->bsd", attn, ap["wo"].astype(dtype))
-        x = _gated_residual(x, g_a, attn, up=up)
-
-        h = _mod_norm(x, up=up)
-        ca, _ = L.attention_apply(p["cross"], h, cfg, causal=False,
-                                  kv_x=txt, use_rope=False)
-        x = x + ca
-
-        h = _mod_norm(x, sh_m, sc_m, up=up)
-        x = _gated_residual(x, g_m, L.swiglu_apply(p["mlp"], h), up=up)
-
-    mods = jnp.einsum("bd,dk->bk", jax.nn.silu(c),
-                      params["final_ada_w"].astype(dtype)) \
-        + params["final_ada_b"].astype(dtype)
-    sh, sc = jnp.split(mods, 2, axis=-1)
-    x = _mod_norm(x, sh, sc, up=up)
-    return jnp.einsum("bnd,dp->bnp", x, params["final_out"].astype(dtype))
+            x = _post(blocks, i, x, mods, txt, q, *kv, cfg=cfg)
+    return _tail(params, x, c, cfg=cfg)

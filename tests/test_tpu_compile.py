@@ -99,3 +99,82 @@ def test_compile_cache_dir_from_environment():
     assert compile_cache_dir({"JAX_COMPILATION_CACHE_DIR": "/x"}) is None
     assert compile_cache_dir({}) == str(CHECKOUT / ".jax_cache")
     assert compile_cache_dir({}) == compile_cache_dir({})
+
+
+# the served step's layer programs (models/dit.py) at the benchmark's
+# widths: PixArt-Sigma XL/2 at 1024 px (B=2 for batched CFG, 4,096
+# tokens, 16 heads of 72) and Wan2.2 TI2V-5B's widths at 720p 49 frames
+# (B=1, 11,440 tokens, 24 heads of 128), float32 as served
+HBM_BYTES = 16e9
+TEXT_LEN = 77
+
+
+def _served_config(which):
+    from dataclasses import replace
+    from repro.configs.dit_models import DIT_IMAGE, DIT_VIDEO
+    if which == "pixart":
+        cfg = DIT_IMAGE.with_(d_model=1152, num_heads=16, num_kv_heads=16,
+                              head_dim=72, d_ff=4608,
+                              dit=replace(DIT_IMAGE.dit, in_channels=4,
+                                          cond_dim=4096))
+        return cfg.with_(use_pallas=True), 2, 4096
+    cfg = DIT_VIDEO.with_(num_layers=4, d_ff=14336,
+                          dit=replace(DIT_VIDEO.dit, in_channels=48,
+                                      cond_dim=4096))
+    return cfg.with_(use_pallas=True), 1, 11440
+
+
+def _custom_calls(hlo):
+    return set(re.findall(r"%([A-Za-z_]+)\.\d+ = \S+ custom-call\(.*"
+                          r"custom_call_target=\"tpu_custom_call\"", hlo))
+
+
+def _adaln_tensors_in_vmem(hlo, tensor):
+    """The adaLN calls' (B, N, D) operands and results that XLA placed
+    in VMEM (memory space ``S(1)``) rather than HBM."""
+    shape_of = dict(re.findall(r"^\s*(?:ROOT )?%(\S+) = (\S+) ", hlo, re.M))
+    calls = re.findall(r"%(adaln_modulate\.\d+) = \S+ custom-call\(([^)]*)\)",
+                       hlo)
+    names = [c for c, _ in calls] + [a.strip().lstrip("%") for _, args in
+                                     calls for a in args.split(",")]
+    return [n for n in names if shape_of[n].startswith(tensor)
+            and "S(1)" in shape_of[n]]
+
+
+@pytest.mark.parametrize("which", ["pixart", "wan"])
+def test_layer_programs_compile_at_served_width(one_chip, compiled_kernels,
+                                                which):
+    from repro.models import dit
+    from repro.models.layers import split_params
+    cfg, b, n = _served_config(which)
+    params = jax.eval_shape(
+        lambda: split_params(dit.init(jax.random.PRNGKey(0), cfg))[0])
+    weights = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree.leaves(params))
+
+    def on_chip(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    blocks = jax.tree.map(lambda a: on_chip(a.shape, a.dtype),
+                          params["blocks"])
+    layer = on_chip((), jnp.int32)
+    d, heads, hd = cfg.d_model, cfg.num_heads, cfg.head_dim
+    x, c = on_chip((b, n, d)), on_chip((b, d))
+    mods, txt = on_chip((b, 6 * d)), on_chip((b, TEXT_LEN, d))
+    qkv = on_chip((b, n, heads, hd))
+    pre = dit._pre.lower(blocks, layer, x, c, cfg=cfg).compile()
+    post = dit._post.lower(blocks, layer, x, mods, txt, qkv, qkv, qkv,
+                           cfg=cfg).compile()
+    assert _custom_calls(pre.as_text()) == {"adaln_modulate"}
+    assert _custom_calls(post.as_text()) == {"adaln_modulate",
+                                             "flash_attention"}
+    tensor = f"f32[{b},{n},{d}]"
+    for prog in (pre, post):
+        # adaLN streams its tensors through HBM, the pass its roofline
+        # counts, inside a program as on its own
+        assert not _adaln_tensors_in_vmem(prog.as_text(), tensor)
+        m = prog.memory_analysis()
+        # the program's arguments count the stacked weights it reads a
+        # second time, beside all the weights the device holds
+        assert weights + m.argument_size_in_bytes + m.output_size_in_bytes \
+            + m.temp_size_in_bytes < HBM_BYTES
