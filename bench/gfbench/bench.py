@@ -46,7 +46,7 @@ def measure(cell: dict, seed: int, seconds: float, traced: bool,
     import jax
     from repro.core.event_loop import EventLoop, WallClock
     from repro.core.telemetry import Telemetry
-    conf, mix = cell["config"], cell["mix"]
+    conf, mix, arch = cell["config"], cell["mix"], cell["arch"]
     model, text_len = conf["model"], conf["text_encoder"]["prompt_len"]
     chips = cell["chips"]
 
@@ -59,14 +59,15 @@ def measure(cell: dict, seed: int, seconds: float, traced: bool,
     eng.backend.t0 = clock.t0
     if tel is not None:
         tel.t0 = clock.t0
-    rec = serve.Recorder()
+    rec = serve.Recorder(arch)
     rec.install(eng, clock)
     loop = EventLoop(eng.cp, clock)
     serve.warm_up(eng, loop, clock, traffic.warm_set(mix, seed),
                   mix["model"])
     _log(f"set-up: warm-up {clock.now():.2f} s, {len(compiles)} compiles "
          f"so far")
-    planned = traffic.generate(mix, model, peak, seconds, seed, text_len)
+    planned = traffic.generate(mix, model, arch, peak, seconds, seed,
+                               text_len)
     trace_dir = out_dir / "traces" / f"{cell['name']}-{seed}"
     if traced:
         shutil.rmtree(trace_dir, ignore_errors=True)
@@ -95,10 +96,11 @@ def measure(cell: dict, seed: int, seconds: float, traced: bool,
         "worker_errors": len(eng.backend.errors),
         "collective_timeouts": len(eng.backend.timeouts),
         "setup_s": setup_s, "model": model, "text_len": text_len,
+        "arch": conf["architecture"], "root": str(root),
         "peak": peak, "trace": None,
     }
     items = check.collect(eng.cp, window.denoise_steps(run), seed,
-                          mix["check"]["steps"])
+                          mix["check"]["steps"], arch)
     for err in eng.backend.errors[:2]:
         _log(f"worker error: {err}")
     eng.shutdown()
@@ -141,7 +143,7 @@ def measure(cell: dict, seed: int, seconds: float, traced: bool,
                                "idle_gaps": trace.idle_gaps(tr, a, b)}
 
     t_ref = time.monotonic()
-    ref = check.Reference(conf, seed)
+    ref = check.Reference(conf, seed, arch)
     found = check.gaps(ref, items)
     _log(f"reference: {time.monotonic() - t_ref:.2f} s for "
          f"{len(items)} sampled outputs")

@@ -1,5 +1,6 @@
 """What decides ``correct``: the timed path's own outputs against the plain
-reference (``gfbench.reference``), at the timed sizes.
+reference (``gfbench.reference`` and the cell's architecture,
+``bench/archs/<architecture>.py``), at the timed sizes.
 
 Once the window has closed, a sample drawn from the seed is taken of
 what the window produced, and copied to the host before the program is
@@ -7,12 +8,13 @@ freed:
 
 * ``step``: denoise steps completed in the window, the latest one always
   among them.  ``step_gap`` compares the velocity the program applied,
-  ``(x_out - x_in) / (sigma_next - sigma_now)`` (guided merge included),
-  with the reference velocity at the program's ``x_in``; ``state_gap``
-  compares the state it produced, ``x_out``, with
-  ``x_in + (sigma_next - sigma_now) * v_ref``;
-* ``encode``: the text embeddings of those steps' requests (both rows
-  where guided);
+  ``(x_out - x_in) / (sigma_next - sigma_now)``, with the reference
+  velocity at the program's ``x_in`` (the architecture's rows, merged as
+  it merges them); ``state_gap`` compares the state it produced,
+  ``x_out``, with ``x_in + (sigma_next - sigma_now) * v_ref``;
+* ``encode``: the text embeddings of those steps' requests (the
+  unconditional one too where the architecture runs an unconditional
+  row);
 * ``latent0``: their initial noisy latents.
 
 Each number is the widest relative L2 gap over its sample, against the
@@ -36,9 +38,10 @@ def _data(graph, aid):
     return art.data[art.layout.ranks[0]]
 
 
-def collect(cp, steps: list, seed: int, n_steps: int) -> list:
+def collect(cp, steps: list, seed: int, n_steps: int, arch) -> list:
     """Host copies of a seeded sample of the window's outputs.  ``steps``
-    are the window's denoise completions (``window.denoise_steps``)."""
+    are the window's denoise completions (``window.denoise_steps``);
+    ``arch`` says which rows a guided request runs."""
     rng = np.random.Generator(np.random.PCG64([seed % 2 ** 63, 7]))
     done = sorted((s for s in steps if s["finish"] is not None),
                   key=lambda s: s["finish"])
@@ -65,10 +68,11 @@ def collect(cp, steps: list, seed: int, n_steps: int) -> list:
         enc = next(t for t in g.tasks.values() if t.kind == "encode")
         txt = _data(g, enc.outputs[0])
         r = g.request
+        uncond = "uncond" in arch.rows(r.guidance)
         items.append({"what": "encode", "req": rid, "guidance": r.guidance,
                       "embeds": np.asarray(txt["embeds"]),
                       "embeds_uncond": (np.asarray(txt["embeds_uncond"])
-                                        if r.guidance is not None else None)})
+                                        if uncond else None)})
         items.append({"what": "latent0", "req": rid, "steps": r.steps,
                       "latent": np.asarray(
                           _data(g, enc.outputs[1])["latent"])})
@@ -77,12 +81,14 @@ def collect(cp, steps: list, seed: int, n_steps: int) -> list:
 
 class Reference:
     """The reference's weights for one run and its answers, at float32
-    or, as the control, at bfloat16; matmuls at ``highest``."""
+    or, as the control, at bfloat16; matmuls at ``highest``.  The DiT
+    block is the architecture's (``arch``)."""
 
-    def __init__(self, conf: dict, seed: int):
+    def __init__(self, conf: dict, seed: int, arch):
         self.conf = conf
+        self.arch = arch
         self.te = conf["text_encoder"]
-        self.dit, self.txt = R.make_weights(conf, seed)
+        self.dit, self.txt = R.make_weights(conf, seed, arch)
         self._embeds: dict = {}
 
     def embeds(self, rid: str, uncond: bool, dtype):
@@ -98,17 +104,17 @@ class Reference:
 
     def step(self, it: dict, dtype):
         """(velocity rows, sigma now, sigma next) at the program's
-        ``x_in``: [cond] or [cond, uncond] where guided."""
+        ``x_in``: one row for each of the architecture's ``rows``."""
         s_now, s_next = R.sigma_pair(it["steps"], it["step"],
                                      self.conf["flow_shift"])
-        emb = [self.embeds(it["req"], False, dtype)]
-        if it["guidance"] is not None:
-            emb.append(self.embeds(it["req"], True, dtype))
+        emb = [self.embeds(it["req"], row == "uncond", dtype)
+               for row in self.arch.rows(it["guidance"])]
         b = len(emb)
         x = jnp.asarray(it["x_in"], jnp.float32)
         t = jnp.full((b,), R.timestep(s_now), jnp.float32)
-        v = R.velocity(self.dit, jnp.broadcast_to(x, (b,) + x.shape), t,
-                       jnp.asarray(np.stack(emb)), dtype=dtype)
+        v = self.arch.velocity(
+            self.dit, jnp.broadcast_to(x, (b,) + x.shape), t,
+            jnp.asarray(np.stack(emb)), it["guidance"], dtype=dtype)
         return np.asarray(v), s_now, s_next
 
 
@@ -135,12 +141,12 @@ def gaps(ref: Reference, items: list, control: bool = False) -> dict:
             w = it["what"]
             if w == "step":
                 rows, s_now, s_next = ref.step(it, jnp.float32)
-                want = R.guided(rows, it["guidance"])
+                want = ref.arch.merge(rows, it["guidance"])
                 dt = np.float32(s_next - s_now)
                 x_in = np.asarray(it["x_in"], np.float32)
                 if control:
-                    got = R.guided(ref.step(it, jnp.bfloat16)[0],
-                                   it["guidance"])
+                    got = ref.arch.merge(ref.step(it, jnp.bfloat16)[0],
+                                         it["guidance"])
                     x_out = _control_state(x_in, got, s_now, s_next)
                 else:
                     x_out = np.asarray(it["x_out"], np.float32)
@@ -171,9 +177,10 @@ def gaps(ref: Reference, items: list, control: bool = False) -> dict:
 
 def fault_readings(ref: Reference, items: list) -> dict:
     """``step_gap`` and ``state_gap`` as two faults would read them: a
-    step that returns its state unchanged applies no velocity; a guided
-    step with half of its batch (the unconditional row) left out applies
-    the conditional velocity alone."""
+    step that returns its state unchanged applies no velocity; where the
+    architecture runs a guided step as a conditional and an unconditional
+    row, a step with half of its batch (the unconditional row) left out
+    applies the conditional velocity alone."""
     out: dict = {}
 
     def widest(name, value):
@@ -184,13 +191,13 @@ def fault_readings(ref: Reference, items: list) -> dict:
             if it["what"] != "step":
                 continue
             rows, s_now, s_next = ref.step(it, jnp.float32)
-            want = R.guided(rows, it["guidance"])
+            want = ref.arch.merge(rows, it["guidance"])
             dt = float(s_next - s_now)
             x_in = np.asarray(it["x_in"], np.float64)
             x_ref = x_in + dt * np.asarray(want, np.float64)
             widest("unchanged_state.step_gap", 1.0)
             widest("unchanged_state.state_gap", R.rel_l2(x_in, x_ref))
-            if it["guidance"] is not None:
+            if "uncond" in ref.arch.rows(it["guidance"]):
                 widest("half_batch.step_gap", R.rel_l2(rows[0], want))
                 widest("half_batch.state_gap", R.rel_l2(
                     x_in + dt * np.asarray(rows[0], np.float64), x_ref))

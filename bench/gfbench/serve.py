@@ -13,6 +13,7 @@ installs on the instances (``schedule_point``, ``execute`` by task kind,
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib
 import time
 from typing import Optional
@@ -22,32 +23,36 @@ import jax
 from gfbench import reference
 from gfbench.traffic import Planned
 
-MODEL_KEYS = ("num_layers", "d_model", "num_heads", "head_dim", "d_ff")
-DIT_KEYS = ("patch_size", "in_channels", "cond_dim", "latent_frames")
-
 
 def program_config(conf: dict):
     """The program's config named by a configuration file, with the
-    file's changes and the kernel path on; refused where its sizes are
-    not those the file states."""
+    file's changes and the kernel path on; refused where a key of the
+    file's ``model`` table is on neither the config nor its ``dit``, or
+    holds another value there."""
     prog = conf["program"]
     base = getattr(importlib.import_module(prog["module"]), prog["name"])
     changes = dict(prog.get("changes", {}))
     if "dit" in changes:
         changes["dit"] = dataclasses.replace(base.dit, **changes["dit"])
     cfg = base.with_(use_pallas=True, **changes)
-    got = {k: getattr(cfg, k) for k in MODEL_KEYS}
-    got.update({k: getattr(cfg.dit, k) for k in DIT_KEYS})
-    want = {k: conf["model"][k] for k in got}
+    want = conf["model"]
+    lacks = [k for k in want
+             if not hasattr(cfg, k) and not hasattr(cfg.dit, k)]
+    if lacks:
+        raise ValueError(f"program config has no {lacks}")
+    got = {k: getattr(cfg if hasattr(cfg, k) else cfg.dit, k)
+           for k in want}
     if got != want:
         raise ValueError(f"program config {got} is not the file's {want}")
     return cfg
 
 
 class Recorder:
-    """Host spans and step completions of one run, in monotonic time."""
+    """Host spans and step completions of one run, in monotonic time; a
+    denoise step's rows are those its request runs under ``arch``."""
 
-    def __init__(self):
+    def __init__(self, arch):
+        self.arch = arch
         self.spans: list = []           # (name, t0, t1)
         self.completions: list = []     # dicts, plane time
         self.hold = False               # policy dispatches nothing
@@ -69,7 +74,8 @@ class Recorder:
         cp = eng.cp
         cp.schedule_point = self.span(lambda: ("bench.schedule_point", {}),
                                       cp.schedule_point)
-        eng.pipeline.execute = self.span(_exec_span, eng.pipeline.execute)
+        eng.pipeline.execute = self.span(
+            functools.partial(_exec_span, self.arch), eng.pipeline.execute)
         eng.comm.all_gather = self.span(lambda *a: ("bench.all_gather", {}),
                                         eng.comm.all_gather)
         clock.wait = self.span(lambda *a: ("bench.clock_wait", {}),
@@ -88,7 +94,7 @@ class Recorder:
                     "task": task.id, "req": task.request_id,
                     "kind": task.kind, "step": task.step_index,
                     "tokens": task.meta.get("tokens"),
-                    "rows": 2 if req.guidance is not None else 1,
+                    "rows": len(self.arch.rows(req.guidance)),
                     "degree": layout.degree,
                     "start": c.finish_time - c.duration,
                     "finish": c.finish_time, "duration": c.duration,
@@ -97,15 +103,16 @@ class Recorder:
         cp.on_completion = record
 
 
-def _exec_span(task, layout, rank, comm, graph, *rest):
+def _exec_span(arch, task, layout, rank, comm, graph, *rest):
     """``bench.exec.<kind>``; a denoise step carries its request's token
-    count, its rows (2 for batched guidance) and its SP degree."""
+    count, the rows this rank's group runs (the architecture's rows for
+    the request, split over the layout's CFG branches) and its SP
+    degree."""
     if task.kind != "denoise":
         return f"bench.exec.{task.kind}", {}
-    guided = graph.request.guidance is not None and layout.cfg == 1
+    rows = len(arch.rows(graph.request.guidance)) // layout.cfg
     return "bench.exec.denoise", {"tokens": task.meta["tokens"],
-                                  "rows": 2 if guided else 1,
-                                  "degree": layout.degree}
+                                  "rows": rows, "degree": layout.degree}
 
 
 def build(conf: dict, seed: int, ranks: int, telemetry=None):

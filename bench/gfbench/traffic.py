@@ -1,7 +1,9 @@
 """The benchmark's traffic.  A mix file under ``bench/traffic`` names a
 ``kind`` and its parameters; the generator of that kind,
 ``bench/generators/<kind>.py``, turns it into planned requests from the
-run's seed (``plan``).  The program receives only the requests.
+run's seed (``plan``), given the configuration's sizes and architecture
+(its operation counts and the rows a guided request runs).  The program
+receives only the requests.
 
 A generator gives every seed the same work, in another order where
 order matters, so that runs of different seeds differ by arrangement
@@ -35,11 +37,11 @@ def tokens(model: dict, c: dict) -> int:
     return f * (c["height"] // 8 // p) * (c["width"] // 8 // p)
 
 
-def generate(mix: dict, model: dict, peak: dict, seconds: float,
+def generate(mix: dict, model: dict, arch, peak: dict, seconds: float,
              seed: int, text_len: int, root=spec.ROOT) -> list[Planned]:
     """The planned requests of one run, sorted by due time."""
     plan = spec.generator(mix["kind"], root)
-    return sorted(plan(mix, model, peak, seconds, seed, text_len),
+    return sorted(plan(mix, model, arch, peak, seconds, seed, text_len),
                   key=lambda p: p.due)
 
 

@@ -1,13 +1,15 @@
 """step_mfu: percent of the chip's peak that the window's denoise steps
-reached: their operations (``flops.step_flops``, both guidance rows,
-nothing recomputed), each weighted by its share inside the window, over
-the window's seconds times the peak FLOP/s of ``bench/peaks.json``."""
-from gfbench import flops, window
+reached: their operations (the cell's architecture's ``step_flops``, on
+every row a step runs, nothing recomputed), each weighted by its share
+inside the window, over the window's seconds times the peak FLOP/s of
+``bench/peaks.json``."""
+from gfbench import spec, window
 
 
 def read(run):
-    done = sum(flops.step_flops(run["model"], s["tokens"], s["rows"],
-                                run["text_len"]) * s["share"]
+    step_flops = spec.arch_of(run).step_flops
+    done = sum(step_flops(run["model"], s["tokens"], s["rows"],
+                          run["text_len"]) * s["share"]
                for s in window.denoise_steps(run))
     if not done:
         return None

@@ -7,7 +7,8 @@ From the root of a checkout.  Builds the cell's engine with weights made
 from the seed, warms every shape its traffic uses (set-up, ``setup_s``),
 serves the traffic for ``--seconds`` through ``ServingEngine``'s control
 plane and event loop, then compares what the window produced with the
-plain float32 reference in ``bench/gfbench/reference.py``.  With
+plain float32 reference (``bench/gfbench/reference.py`` and the
+configuration's architecture, ``bench/archs/<architecture>.py``).  With
 ``--trace 1`` a profiler trace of the window's steady part gives the
 per-layer metrics and a breakdown.
 

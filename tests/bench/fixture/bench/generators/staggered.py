@@ -6,7 +6,7 @@ import numpy as np
 from gfbench.traffic import Planned
 
 
-def plan(mix, model, peak, seconds, seed, text_len):
+def plan(mix, model, arch, peak, seconds, seed, text_len):
     (cls, _), = mix["mix"].items()
     c = mix["classes"][cls]
     due = np.arange(mix["count"]) * mix["gap_s"]

@@ -4,12 +4,14 @@ control's state held in bfloat16, and the verdict over every number."""
 import numpy as np
 import pytest
 
-from gfbench import check
+from gfbench import check, spec
 
 
 class _Ref:
-    """A stand-in reference: velocity rows fixed by hand."""
+    """A stand-in reference: velocity rows fixed by hand, merged and
+    faulted by the benchmarked configurations' architecture."""
     conf = {"flow_shift": 3.0}
+    arch = spec.arch("adaln-cross-swiglu")
 
     def __init__(self, rows):
         self.rows = np.asarray(rows, np.float32)

@@ -1,6 +1,7 @@
 """The plain reference against the program at a CPU size: the weights it
-derives from the seed are the program's, and its velocity and text
-encoder agree with the served path's."""
+and the configuration's architecture derive from the seed are the
+program's, and the architecture's velocity and the text encoder agree
+with the served path's."""
 import json
 
 import jax
@@ -10,9 +11,10 @@ import pytest
 
 from conftest import FIXTURE
 from gfbench import reference as R
-from gfbench import serve
+from gfbench import serve, spec
 
 SEED = 3000000007
+ARCH = spec.arch("adaln-cross-swiglu")
 
 
 @pytest.fixture(scope="module")
@@ -39,7 +41,7 @@ def _leaves(tree):
 
 def test_bench_reference_weights_are_the_programs(conf, program):
     _, pipe = program
-    dit, txt = R.make_weights(conf, SEED)
+    dit, txt = R.make_weights(conf, SEED, ARCH)
     got, want = _leaves(dit), _leaves(pipe.dit_params)
     assert set(got) == set(want)
     prog_txt = dict(pipe.txt_params)
@@ -58,7 +60,7 @@ def test_bench_reference_weights_are_the_programs(conf, program):
 def test_bench_reference_forward_matches_program(conf, program):
     from repro.models import dit, text_encoder
     cfg, pipe = program
-    dw, tw = R.make_weights(conf, SEED)
+    dw, tw = R.make_weights(conf, SEED, ARCH)
     te = conf["text_encoder"]
     toks = R.prompt_tokens("s1-r0001", te)
     emb = text_encoder.encode(pipe.txt_params, toks, pipe.txt_cfg,
@@ -71,7 +73,7 @@ def test_bench_reference_forward_matches_program(conf, program):
     got = dit.forward_sp_tokens(pipe.dit_params, x, t, txt, cfg,
                                 pos_offset=0, n_total=64,
                                 kv_gather=lambda k, v, i: (k, v))
-    assert R.rel_l2(got, R.velocity(dw, x, t, txt)) < 1e-5
+    assert R.rel_l2(got, ARCH.velocity(dw, x, t, txt, None)) < 1e-5
 
 
 def test_bench_reference_inputs_match_program():

@@ -68,6 +68,7 @@ def _hand():
 def _run(tr, model=RECORDED_MODEL):
     return {"trace": {"events": tr, "span": trace.window(tr)},
             "model": model, "text_len": 77, "peak": PEAK,
+            "arch": "adaln-cross-swiglu", "root": str(spec.ROOT),
             "window": {"w0": 0.0, "w1": 1.0, "seconds": 1.0},
             "steps": [], "spans": []}
 

@@ -1,7 +1,9 @@
-"""Every cell resolves by name to its configuration, mix, traffic
-generator and metric readers, a cell and a generator that exist only in
-a test fixture resolve the same way, and BENCHMARK.json keeps to the
-benchmark's contract."""
+"""Every cell resolves by name to its configuration, architecture, mix,
+traffic generator and metric readers, a cell and a generator that exist
+only in a test fixture resolve the same way, the program's config is
+held to every size a configuration file states, and BENCHMARK.json keeps
+to the benchmark's contract."""
+import copy
 import json
 import re
 
@@ -19,6 +21,7 @@ def test_bench_cell_resolves(cell):
     c = spec.resolve(BENCH, cell)
     assert c["chips"] in (1, 4)
     assert {"model", "text_encoder", "program"} <= set(c["config"])
+    assert c["arch"] is spec.arch(c["config"]["architecture"])
     assert set(c["limits"]) == set(check.NUMBERS)
     assert callable(spec.generator(c["mix"]["kind"]))
     names = {m["name"] for m in c["end_to_end"]}
@@ -32,13 +35,26 @@ def test_bench_cell_resolves(cell):
 def test_bench_cell_traffic_is_the_same_work_for_every_seed(cell, peak):
     c = spec.resolve(BENCH, cell)
     model, lt = c["config"]["model"], c["config"]["text_encoder"]["prompt_len"]
-    runs = [traffic.generate(c["mix"], model, peak, BENCH["run_seconds"],
-                             s, lt) for s in (5, 2 ** 31 + 7)]
+    runs = [traffic.generate(c["mix"], model, c["arch"], peak,
+                             BENCH["run_seconds"], s, lt)
+            for s in (5, 2 ** 31 + 7)]
     shape = [sorted((p.cls, p.height, p.width, p.frames, p.steps, p.guidance,
                      p.due) for p in r) for r in runs]
     assert shape[0] == shape[1] and len(runs[0]) >= 2
     assert len({p.id for p in runs[0]} | {p.id for p in runs[1]}) == \
         2 * len(runs[0])
+
+
+# N at the v5e's peak: 2 x 51 s over 50 steps at the step's least time
+@pytest.mark.parametrize("cell,n", [("image-batch-1c", 27),
+                                    ("video-batch-1c", 17)])
+def test_bench_batch_size_is_pinned(cell, n, peak):
+    c = spec.resolve(BENCH, cell)
+    conf = c["config"]
+    got = traffic.generate(c["mix"], conf["model"], c["arch"], peak,
+                           BENCH["run_seconds"], 2 ** 31 + 5,
+                           conf["text_encoder"]["prompt_len"])
+    assert len(got) == n
 
 
 def test_bench_fixture_generator_resolves_by_name(fixture_root, peak):
@@ -47,7 +63,7 @@ def test_bench_fixture_generator_resolves_by_name(fixture_root, peak):
            "mix": {"S": 1.0}, "steps": 2, "count": 4, "gap_s": 0.5}
     with pytest.raises(FileNotFoundError):
         spec.generator("staggered")
-    got = traffic.generate(mix, {"patch_size": 2}, peak, 10.0, 3, 77,
+    got = traffic.generate(mix, {"patch_size": 2}, None, peak, 10.0, 3, 77,
                            root=fixture_root)
     assert [p.due for p in got] == [0.0, 0.5, 1.0, 1.5]
     assert sorted(p.id for p in got) == [f"s3-g{i:03d}" for i in range(4)]
@@ -75,6 +91,21 @@ def test_bench_config_matches_program(conf):
     assert c["source"].startswith("https://")
     entry = {x["file"]: x for x in BENCH["configs"]}[f"bench/configs/{conf}"]
     assert entry["reduced"] == c["reduced"] and entry["source"] == c["source"]
+
+
+@pytest.mark.parametrize("change,refusal", [
+    (("d_ff", 4096), "is not the file's"),
+    (("num_double_layers", 4), "has no"),
+    (("latent_frames", 2), "is not the file's")])
+def test_bench_program_config_refuses_a_size_it_does_not_hold(
+        change, refusal):
+    with open(REPO / "bench" / "configs" / "pixart-sigma-xl2-1024.json") as f:
+        c = json.load(f)
+    serve.program_config(c)
+    bad = copy.deepcopy(c)
+    bad["model"][change[0]] = change[1]
+    with pytest.raises(ValueError, match=refusal):
+        serve.program_config(bad)
 
 
 def test_bench_table_keeps_to_contract():

@@ -7,9 +7,10 @@ import json
 import pytest
 
 from conftest import FIXTURE
-from gfbench import flops, trace
+from gfbench import flops, spec, trace
 
 PEAK = {"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+ARCH = spec.arch("adaln-cross-swiglu")
 MODEL = {"num_layers": 1, "d_model": 128, "num_heads": 2, "head_dim": 64,
          "d_ff": 256, "patch_size": 2, "in_channels": 16, "cond_dim": 64}
 
@@ -52,8 +53,8 @@ def test_bench_roofline_share_over_traced_steps():
     run = {"trace": {"events": tr, "span": (0, 1000)}, "model": MODEL,
            "text_len": 77, "peak": PEAK}
     share = trace.roofline_share(
-        run, flops.step_flash, lambda mod, name: "flash" in mod, "flash")
-    least = 2 * flops.least_time(*flops.step_flash(MODEL, 256, 2, 77),
+        run, ARCH.step_flash, lambda mod, name: "flash" in mod, "flash")
+    least = 2 * flops.least_time(*ARCH.step_flash(MODEL, 256, 2, 77),
                                  PEAK)[0]
     assert share == pytest.approx(100 * least / 500e-9)
     spans = trace.denoise_spans(tr, 0, 1000)
@@ -62,12 +63,12 @@ def test_bench_roofline_share_over_traced_steps():
 
 def test_bench_nothing_traced_reads_none():
     run = {"trace": None}
-    assert trace.roofline_share(run, flops.step_flash,
+    assert trace.roofline_share(run, ARCH.step_flash,
                                 lambda m, n: True, "x") is None
     tr = {"host": [["bench.traced_window", 0, 10, {}]], "device": []}
     run = {"trace": {"events": tr, "span": (0, 10)}, "model": MODEL,
            "text_len": 77, "peak": PEAK}
-    assert trace.roofline_share(run, flops.step_flash,
+    assert trace.roofline_share(run, ARCH.step_flash,
                                 lambda m, n: True, "x") is None
 
 
@@ -104,9 +105,9 @@ def test_bench_recorded_trace_rooflines(recorded):
            "model": RECORDED_MODEL, "text_len": 77, "peak": PEAK}
     assert len(trace.denoise_spans(recorded, *run["trace"]["span"])) == 1
     flash = trace.roofline_share(
-        run, flops.step_flash,
+        run, ARCH.step_flash,
         lambda mod, name: name.startswith("flash_attention"), "flash")
     adaln = trace.roofline_share(
-        run, flops.step_adaln,
+        run, ARCH.step_adaln,
         lambda mod, name: name.startswith("adaln_modulate"), "adaln")
     assert 0 < flash < 100 and 0 < adaln < 100
