@@ -77,6 +77,24 @@ class DiTConfig:
     num_steps: int = 50           # default denoising steps
     # video: frames in latent space (1 -> image model)
     latent_frames: int = 1
+    # block family: "adaln" (one kind of adaLN-Zero block with cross-
+    # attention to fixed text) or "flux" (``num_layers`` double-stream
+    # then ``num_single_layers`` single-stream blocks over text and image
+    # tokens in one attention; models/flux.py)
+    blocks: str = "adaln"
+    num_single_layers: int = 0
+    # the guidance scale is a model input (FLUX.1-dev): a guided step is
+    # one row, with no unconditional pass
+    guidance_embeds: bool = False
+    pooled_dim: int = 0           # pooled text vector width ("flux")
+    rope_axes: tuple = ()         # 3-axis RoPE widths over head_dim ("flux")
+    text_len: int = 77            # prompt tokens the text encoder emits
+    flow_shift: float = 3.0       # the flow sampler's sigma shift
+
+    def __post_init__(self):
+        # a list from a JSON file would leave the config unhashable, and
+        # the layer programs take the config as a static argument
+        object.__setattr__(self, "rope_axes", tuple(self.rope_axes))
 
 
 @dataclass(frozen=True)
@@ -190,7 +208,17 @@ class ModelConfig:
             kw["shared_attn_every"] = 2
             kw["num_layers"] = 4
         if self.dit is not None:
-            kw["dit"] = replace(self.dit, cond_dim=64, num_steps=4)
+            # widths a config leaves unset (0, ()) stay unset; RoPE axes
+            # keep their shares of the cut head; T5's 512-token prompt
+            # becomes CLIP's 77
+            dc = self.dit
+            kw["dit"] = replace(
+                dc, cond_dim=64, num_steps=4,
+                pooled_dim=min(dc.pooled_dim, 32),
+                num_single_layers=min(dc.num_single_layers, 2),
+                text_len=min(dc.text_len, 77),
+                rope_axes=tuple(a * kw["head_dim"] // self.head_dim
+                                for a in dc.rope_axes))
         kw.update(overrides)
         return replace(self, **kw)
 

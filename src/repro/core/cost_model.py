@@ -395,8 +395,7 @@ class CostModel:
         knowing shapes exist; pass ``cfg>=2`` to price a split shape.
         Guided steps bypass the feature cache, so no mixture applies.
         """
-        if cfg == 0 and getattr(graph.request, "guidance", None) \
-                is not None:
+        if cfg == 0 and graph.request.cfg_branches == 2:
             cfg = 1
         total = 0.0
         for t in graph.remaining_tasks():

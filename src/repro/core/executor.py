@@ -137,10 +137,11 @@ class ThreadBackend:
         if tel is None:
             self.adapter.execute(task, layout, rank, self.comm, graph, desc)
             return
-        guided = graph.request.guidance is not None and layout.cfg == 1
+        # a CFG split runs one of the request's branches on each rank
+        rows = graph.request.cfg_branches // layout.cfg
         with tel.region(f"gfdit.task.{task.kind}", task=id_number(task.id),
                         seq=seq, step=task.step_index,
-                        tokens=task.meta["tokens"], rows=2 if guided else 1,
+                        tokens=task.meta["tokens"], rows=rows,
                         degree=layout.degree, rank=rank):
             self.adapter.execute(task, layout, rank, self.comm, graph, desc)
 

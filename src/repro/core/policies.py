@@ -422,7 +422,7 @@ class PackingPolicy(Policy):
                    running_reqs: set) -> Optional[list]:
         """Pop a greedy, slack-feasible pack off the EDF-sorted member
         list; ``None`` means hold this group for an imminent peer."""
-        model, tokens = sig
+        model, tokens = sig[:2]             # a guided sig adds its scale
         cost = view.cost
         pack = [members.pop(0)]
         i = 0
@@ -603,7 +603,9 @@ class ElasticPolicy(Policy):
 
     def _remaining(self, view, req, g, d, span: int = 1,
                    cfg: int = 0) -> float:
-        itv = self._interval(view) if d > 1 else 1
+        # a model whose layers take no snapshot is never stamped a hit
+        itv = self._interval(view) \
+            if d > 1 and cache_artifact(g) is not None else 1
         return view.cost.request_remaining(req.model, g, d, span,
                                            cache_interval=itv, cfg=cfg)
 
@@ -634,7 +636,7 @@ class ElasticPolicy(Policy):
         participants beat the halved per-branch FLOP share).  Reduces to
         ``(_need_degree, 1)`` exactly when shape search is off or the
         request is unguided, so scalar policies never see shapes."""
-        if not self.hybrid or getattr(req, "guidance", None) is None:
+        if not self.hybrid or req.cfg_branches == 1:
             return self._need_degree(view, req, g), 1
         cands = self._cands(view)
         if not any(t.kind == "denoise" and t.state == "pending"
@@ -981,7 +983,7 @@ class ElasticPolicy(Policy):
                 if rid in reshaped_guard or rid in view.pinned:
                     continue
                 req = view.requests[rid]
-                if getattr(req, "guidance", None) is None:
+                if req.cfg_branches == 1:
                     continue
                 lay = effective_layout(rid)
                 if lay is None or lay.degree < 2 or lay.degree % 2:
@@ -1024,7 +1026,7 @@ class ElasticPolicy(Policy):
         def try_join(t, req, g) -> bool:
             if not (self.pack and t.kind == "denoise"):
                 return False
-            if getattr(req, "guidance", None) is not None:
+            if req.cfg_branches == 2:
                 return False    # packs refuse guided members (§14)
             sig = pack_signature(t, req)
             for pk in open_packs:
@@ -1072,7 +1074,7 @@ class ElasticPolicy(Policy):
             free = [r for r in free if r not in set(ranks)]
             granted[req.id] = granted.get(req.id, 0) + k
             if self.pack and t.kind == "denoise" and \
-                    getattr(req, "guidance", None) is None:
+                    req.cfg_branches == 1:
                 open_packs.append({"sig": pack_signature(t, req), "k": k,
                                    "members": [(t, req, g)],
                                    "ranks": ranks})
@@ -1091,8 +1093,7 @@ class ElasticPolicy(Policy):
             need, ncfg = self._need_shape(view, req, g)
             # bounded hold (DESIGN.md §9): wait one boundary for an
             # imminent compatible peer when that cannot cost the SLO
-            if self.pack and ncfg == 1 and \
-                    getattr(req, "guidance", None) is None and \
+            if self.pack and ncfg == 1 and req.cfg_branches == 1 and \
                     self._pack_hold_ok(view, t, req, g, need,
                                        set(granted), peer_idx,
                                        running_reqs):
@@ -1129,7 +1130,7 @@ class ElasticPolicy(Policy):
             # rule protects the pack's SLO members
             if try_join(t, req, g):
                 continue
-            if self.pack and getattr(req, "guidance", None) is None and \
+            if self.pack and req.cfg_branches == 1 and \
                     self._pack_hold_ok(view, t, req, g, 1,
                                        set(granted), peer_idx,
                                        running_reqs):

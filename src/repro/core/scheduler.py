@@ -380,7 +380,7 @@ class ControlPlane:
         cfg = getattr(layout, "cfg", 1)
         if cfg == 1:
             return True
-        return cfg == 2 and getattr(req, "guidance", None) is not None
+        return cfg == 2 and req.cfg_branches == 2
 
     def _mark_running(self, task: TrajectoryTask, layout: ExecutionLayout,
                       extra_ev: Optional[dict] = None,
@@ -486,7 +486,7 @@ class ControlPlane:
             t, req, g = by_id[tid]
             if t.state != "pending" or t.kind != "denoise":
                 return False
-            if getattr(req, "guidance", None) is not None:
+            if req.cfg_branches == 2:
                 return False        # guided steps never pack (§14)
             members.append((t, req, g))
         sigs = {pack_signature(t, req) for t, req, _ in members}
@@ -500,7 +500,7 @@ class ControlPlane:
             self.pinned.pop(req.id, None)
             self._dispatch(t, a.layout, g)
             return True
-        model, tokens = next(iter(sigs))
+        model, tokens = next(iter(sigs))[:2]    # a guided sig adds its scale
         pack_id = f"pack-{next(self._pack_seq)}"
         membership = [(req.id, t.step_index) for t, req, _ in members]
         # pack-level cache decision (DESIGN.md §11): one set of
@@ -837,9 +837,8 @@ class ControlPlane:
             # guided denoise calibrates its shape cell (DESIGN.md §14):
             # the 2x work must not poison the unguided calibration
             cfg = 0
-            if task.kind == "denoise" and getattr(
-                    self.requests[task.request_id], "guidance",
-                    None) is not None:
+            if task.kind == "denoise" and \
+                    self.requests[task.request_id].cfg_branches == 2:
                 cfg = max(getattr(layout, "cfg", 1), 1)
             model = self.requests[task.request_id].model
             tokens = task.meta.get("tokens", 4096)

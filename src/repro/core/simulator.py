@@ -125,8 +125,7 @@ class SimBackend:
         # guided denoise prices its shape cell (DESIGN.md §14): cfg=1
         # batched on one group, cfg>=2 split branches + merge exchange
         cfg = 0
-        if task.kind == "denoise" and \
-                getattr(graph.request, "guidance", None) is not None:
+        if task.kind == "denoise" and graph.request.cfg_branches == 2:
             cfg = max(getattr(layout, "cfg", 1), 1)
         dur = self.cost.estimate(model, task.kind, tokens, layout.degree,
                                  span=layout.span(self.topology),

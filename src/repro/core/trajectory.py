@@ -260,6 +260,10 @@ class Request:
     # branch groups (cfg>=2), merged v = u + g*(c - u) each step.
     guidance: Optional[float] = None
     # filled by converter
+    # rows a denoise step runs, the request's CFG branch count: 2 (cond +
+    # uncond) for a guided request, 1 unguided or where the model takes
+    # the guidance scale as an input (DiTConfig.guidance_embeds)
+    cfg_branches: int = 1
     task_ids: list[str] = field(default_factory=list)
     done_time: Optional[float] = None
     failed: bool = False

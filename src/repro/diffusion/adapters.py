@@ -21,6 +21,7 @@ from repro.configs.base import ModelConfig
 from repro.core.trajectory import (Artifact, ExecutionLayout, FieldSpec,
                                    Request, RequestGraph, TrajectoryTask,
                                    fresh_id)
+from repro.models import dit
 
 
 # ---------------------------------------------------------------------------
@@ -28,8 +29,12 @@ from repro.core.trajectory import (Artifact, ExecutionLayout, FieldSpec,
 # ---------------------------------------------------------------------------
 
 def convert_request(req: Request, cfg: ModelConfig) -> RequestGraph:
-    """encode -> denoise_0..denoise_{n-1} -> decode, linked by artifacts."""
+    """encode -> denoise_0..denoise_{n-1} -> decode, linked by artifacts.
+    Decides the request's CFG branch count (``req.cfg_branches``), which
+    the pipeline, executor, cost model and policies read."""
     dc = cfg.dit
+    req.cfg_branches = 2 if req.guidance is not None \
+        and not dc.guidance_embeds else 1
     f = req.frames
     f_lat = max(1, (f + 3) // 4) if f > 1 else 1
     h_lat, w_lat = req.height // 8, req.width // 8
@@ -46,14 +51,15 @@ def convert_request(req: Request, cfg: ModelConfig) -> RequestGraph:
         return a
 
     txt_fields = {
-        "embeds": FieldSpec("replicated", (77, dc.cond_dim), "float32"),
+        "embeds": FieldSpec("replicated", (dc.text_len, dc.cond_dim),
+                            "float32"),
     }
-    if req.guidance is not None:
+    if req.cfg_branches == 2:
         # classifier-free guidance (DESIGN.md §14): the null-prompt
         # branch embedding must be DECLARED so the migration planner
         # carries it between layouts like any replicated field
         txt_fields["embeds_uncond"] = FieldSpec(
-            "replicated", (77, dc.cond_dim), "float32")
+            "replicated", (dc.text_len, dc.cond_dim), "float32")
     txt = art("text_embeds", txt_fields)
     enc = TrajectoryTask(id=fresh_id("task"), request_id=req.id,
                          kind="encode", outputs=[txt.id],
@@ -89,14 +95,17 @@ def convert_request(req: Request, cfg: ModelConfig) -> RequestGraph:
     # snapshot of one gather, which is what lets a same-degree
     # Reallocate move a warm cache through the ordinary migration
     # planner.  The codec-declared shapes also give the planner/cost
-    # model an honest byte count for pricing that move.
-    kv_fields: dict[str, FieldSpec] = {}
-    for layer in range(cfg.num_layers):
-        for f in ("k", "v"):
-            kv_fields[f"{f}{layer}"] = FieldSpec(
-                "replicated", (n_tok, cfg.num_kv_heads, cfg.head_dim),
-                "float32")
-    art("kv_cache", kv_fields)
+    # model an honest byte count for pricing that move.  A model whose
+    # layers cannot take the snapshot (FLUX, DESIGN.md §18) declares
+    # none, so the plane never stamps it a hit.
+    if dit.family(cfg).cache_hit:
+        kv_fields: dict[str, FieldSpec] = {}
+        for layer in range(cfg.num_layers):
+            for f in ("k", "v"):
+                kv_fields[f"{f}{layer}"] = FieldSpec(
+                    "replicated", (n_tok, cfg.num_kv_heads, cfg.head_dim),
+                    "float32")
+        art("kv_cache", kv_fields)
 
     out = art("output", {
         "pixels": FieldSpec("replicated",

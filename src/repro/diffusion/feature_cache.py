@@ -8,10 +8,12 @@ hit/refresh policy, and the invalidation rules — as a first-class,
 schedulable, migratable resource:
 
 * **storage** — one ``kv_cache`` artifact per request (created by the
-  converter) holding, per rank, the per-layer gathered K/V from the
-  last *refresh* step.  Every rank's copy is the bit-identical snapshot
-  of that gather (``replicated`` fields), which is what makes the cache
-  migratable through the ordinary layout-aware migration planner.
+  converter for a model whose layers take the snapshot; a FLUX request
+  has none, DESIGN.md §18) holding, per rank, the per-layer gathered
+  K/V from the last *refresh* step.  Every rank's copy is the
+  bit-identical snapshot of that gather (``replicated`` fields), which
+  is what makes the cache migratable through the ordinary layout-aware
+  migration planner.
 * **hit/refresh policy** — a denoise step at the cache's layout within
   ``interval`` steps of the last refresh is a **hit**: the executor
   splices its fresh local K/V shard into the cached remote shards and
@@ -43,7 +45,8 @@ CACHE_ROLE = "kv_cache"
 
 
 def cache_artifact(graph: RequestGraph):
-    """The request's ``kv_cache`` artifact (None on pre-cache graphs)."""
+    """The request's ``kv_cache`` artifact (None on pre-cache graphs and
+    on models whose layers take no stale snapshot)."""
     for a in graph.artifacts.values():
         if a.role == CACHE_ROLE:
             return a
@@ -150,11 +153,10 @@ class FeatureCachePlane:
         if art is None:
             return None
         ent = self.entries.get(task.request_id)
-        if getattr(graph.request, "guidance", None) is not None or \
-                getattr(layout, "cfg", 1) > 1:
-            # guided steps bypass the cache (DESIGN.md §14): the batched
-            # path gathers B=2 branch-specific KV, and split branches
-            # gather DIFFERENT bytes per branch — neither fits the
+        if graph.request.cfg_branches == 2 or getattr(layout, "cfg", 1) > 1:
+            # two-row guided steps bypass the cache (DESIGN.md §14): the
+            # batched path gathers B=2 branch-specific KV, and split
+            # branches gather DIFFERENT bytes per branch — neither fits the
             # one-replicated-snapshot storage contract.  Any residency a
             # request built before turning guided (or before a reshape
             # onto a cfg layout) invalidates with a cfg-change reason.

@@ -76,20 +76,41 @@ class DiTPipeline:
             return self._placed[dev]
 
     def _forward(self, rank: int, x, t, txt, off: int, n_total: int,
-                 kv_gather):
+                 kv_gather, *, guidance=None, grids=None):
         """The denoiser's forward on ``rank``, as the step's
-        ``gfdit.step.forward`` region.  The region's ``builds`` stat
-        counts the layer programs the process built while it ran
-        (``dit.builds``): 0 once every shape is warm."""
+        ``gfdit.step.forward`` region.  The region carries the model's
+        layer count (and, for more than one block kind, each kind's), and
+        its ``builds`` stat counts the layer programs the process built
+        while it ran (``dit.builds``): 0 once every shape is warm."""
+        segs = dit.segments(self.cfg)
+        counts = dict(segs) if len(segs) > 1 else {}
         with self._region("gfdit.step.forward",
-                          layers=self.cfg.num_layers) as late:
+                          layers=sum(n for _, n in segs), **counts) as late:
             before = dit.builds()
             v = dit.forward_sp_tokens(
                 self.weights(rank)[0], x, t, txt, self.cfg, pos_offset=off,
-                n_total=n_total, kv_gather=kv_gather)
+                n_total=n_total, kv_gather=kv_gather, guidance=guidance,
+                grids=grids)
             if late is not None:
                 late["builds"] = dit.builds() - before
         return v
+
+    def _sigmas(self, steps: int):
+        return schedule.flow_sigmas(steps, self.cfg.dit.flow_shift)
+
+    def _scales(self, reqs):
+        """Each row's guidance scale, where the model takes it as an input
+        (None otherwise); an unguided request runs at scale 1."""
+        if not self.cfg.dit.guidance_embeds:
+            return None
+        return jnp.array([1.0 if r.guidance is None else r.guidance
+                          for r in reqs], jnp.float32)
+
+    def _grid(self, task):
+        """(frames, rows, cols) of a denoise task's latent patches."""
+        f, h, w, _ = task.meta["latent_shape"]
+        p = self.cfg.dit.patch_size
+        return (f, h // p, w // p)
 
     # ------------------------------------------------------------------
     # adapter interface: execute this rank's share of a trajectory task
@@ -126,7 +147,7 @@ class DiTPipeline:
             req = graph.request
             txts.append(graph.artifacts[task.inputs[0]].data[rank]["embeds"])
             xs.append(graph.artifacts[task.inputs[1]].data[rank]["latent"])
-            sigmas = schedule.flow_sigmas(req.steps)
+            sigmas = self._sigmas(req.steps)
             step = task.meta["step"]
             s_now = float(sigmas[step])
             s_next = (float(sigmas[step + 1]) if step + 1 < req.steps
@@ -181,7 +202,10 @@ class DiTPipeline:
             x = jnp.stack([jnp.asarray(s) for s in xs])    # (B, N_loc, pd)
             txt = jnp.stack([jnp.asarray(s) for s in txts])  # (B, Lt, cond)
             t = jnp.array(t_steps, jnp.float32)
-        v = self._forward(rank, x, t, txt, off, n_total, kv_gather)
+        v = self._forward(rank, x, t, txt, off, n_total, kv_gather,
+                          guidance=self._scales([g.request
+                                                 for _, g in members]),
+                          grids=tuple(self._grid(tk) for tk, _ in members))
         with self._region("gfdit.step.update"):
             new = [schedule.flow_step(x[i], v[i], s_now, s_next)
                    for i, (s_now, s_next) in enumerate(sig_pairs)]
@@ -199,9 +223,10 @@ class DiTPipeline:
         req = graph.request
         seed = _req_seed(req.id)
         key = jax.random.PRNGKey(seed)
-        # synthetic prompt tokens derived from the request id (length 77
-        # matches the converter's declared text_embeds field shape)
-        toks = jax.random.randint(key, (1, 77), 0, self.txt_cfg.vocab_size)
+        # synthetic prompt tokens derived from the request id (the
+        # config's prompt length, the converter's declared field shape)
+        toks = jax.random.randint(key, (1, self.cfg.dit.text_len), 0,
+                                  self.txt_cfg.vocab_size)
         embeds = text_encoder.encode(txt_params, toks, self.txt_cfg,
                                      dtype=jnp.float32)[0]     # (Lt, cond)
         txt_art = graph.artifacts[task.outputs[0]]
@@ -209,7 +234,7 @@ class DiTPipeline:
         # same-layout successor consumes without migration)
         for r in layout.ranks:
             txt_art.data[r]["embeds"] = np.asarray(embeds)
-        if req.guidance is not None:
+        if req.cfg_branches == 2:
             # classifier-free guidance (DESIGN.md §14): the uncond branch
             # conditions on the null prompt (all-zero tokens)
             toks_u = jnp.zeros_like(toks)
@@ -224,7 +249,7 @@ class DiTPipeline:
         n_tok, patch_dim = lat_art.fields["latent"].global_shape
         noise = jax.random.normal(jax.random.fold_in(key, 1),
                                   (n_tok, patch_dim), jnp.float32)
-        sigmas = schedule.flow_sigmas(req.steps)
+        sigmas = self._sigmas(req.steps)
         full = np.asarray(noise) * sigmas[0]
         view = field_view(lat_art.fields["latent"], layout)
         for r in layout.ranks:
@@ -235,7 +260,7 @@ class DiTPipeline:
     # ------------------------------------------------------------------
     def _denoise(self, task, layout, rank, comm, graph, desc):
         req = graph.request
-        if req.guidance is not None:
+        if req.cfg_branches == 2:
             return self._denoise_guided(task, layout, rank, comm, graph,
                                         desc)
         txt_art = graph.artifacts[task.inputs[0]]
@@ -248,7 +273,7 @@ class DiTPipeline:
         off, size = view.slices[rank]
         n_total = spec.global_shape[0]
 
-        sigmas = schedule.flow_sigmas(req.steps)
+        sigmas = self._sigmas(req.steps)
         step = task.meta["step"]
         sigma_now = float(sigmas[step])
         sigma_next = float(sigmas[step + 1]) if step + 1 < req.steps else 0.0
@@ -303,7 +328,8 @@ class DiTPipeline:
             t = jnp.array([schedule.timestep_of_sigma(sigma_now)],
                           jnp.float32)
         v_shard = self._forward(rank, x[None], t, txt[None], off, n_total,
-                                kv_gather)[0]
+                                kv_gather, guidance=self._scales([req]),
+                                grids=(self._grid(task),))[0]
         with self._region("gfdit.step.update"):
             new_x = schedule.flow_step(x, v_shard, sigma_now, sigma_next)
         with self._region("gfdit.step.fetch", bytes=new_x.nbytes):
@@ -338,7 +364,7 @@ class DiTPipeline:
         off, _ = view.slices[rank]
         n_total = spec.global_shape[0]
 
-        sigmas = schedule.flow_sigmas(req.steps)
+        sigmas = self._sigmas(req.steps)
         step = task.meta["step"]
         sigma_now = float(sigmas[step])
         sigma_next = float(sigmas[step + 1]) if step + 1 < req.steps \
@@ -359,7 +385,8 @@ class DiTPipeline:
                 rows = jnp.stack([x, x])
                 txt = jnp.stack([jnp.asarray(txt_c), jnp.asarray(txt_u)])
                 t = jnp.array([ts, ts], jnp.float32)
-            v = self._forward(rank, rows, t, txt, off, n_total, kv_gather)
+            v = self._forward(rank, rows, t, txt, off, n_total, kv_gather,
+                              grids=(self._grid(task),) * 2)
         else:
             b = layout.branch_of(rank)
             branch = desc.branches[b]
@@ -380,7 +407,8 @@ class DiTPipeline:
                 txt = jnp.asarray(txt_c if b == 0 else txt_u)
                 t = jnp.array([ts], jnp.float32)
             v_mine = self._forward(rank, x[None], t, txt[None], off,
-                                   n_total, kv_gather)[0]
+                                   n_total, kv_gather,
+                                   grids=(self._grid(task),))[0]
         with self._region("gfdit.step.update"):
             if layout.cfg == 1:
                 v_c, v_u = v[0], v[1]

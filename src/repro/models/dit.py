@@ -3,7 +3,7 @@
 adaLN-Zero blocks with self-attention over latent tokens + cross-attention
 to text conditioning (PixArt-style), supporting image (F=1) and video
 (F>1) latents.  The fused modulate op has a Pallas kernel in
-``kernels/adaln.py``; this module is the jnp path / oracle.
+``kernels/adaln.py``; ``dit_parts`` holds its jnp path / oracle.
 
 Token layout: latents (B, F, H, W, C) -> patchify p x p spatial ->
 (B, F*(H/p)*(W/p), p*p*C) -> linear embed -> N tokens.
@@ -11,7 +11,6 @@ Token layout: latents (B, F, H, W, C) -> patchify p x p spatial ->
 from __future__ import annotations
 
 import functools
-import threading
 from typing import Any, Optional
 
 import jax
@@ -20,7 +19,11 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.kernels import ops
+from repro.models import flux
 from repro.models import layers as L
+from repro.models.dit_parts import (Family, built, gated_residual, layer_of,
+                                    mod_norm, timestep_embedding)
+from repro.models.dit_parts import builds  # noqa: F401  (dit.builds())
 from repro.models.layers import ParamSpec, pspec, pzeros, pones
 from repro.sharding.ctx import constrain
 
@@ -28,15 +31,6 @@ from repro.sharding.ctx import constrain
 # ---------------------------------------------------------------------------
 # Embeddings
 # ---------------------------------------------------------------------------
-
-def timestep_embedding(t, dim: int, max_period: float = 10000.0):
-    """Sinusoidal timestep embedding. t: (B,) float in [0, 1000]."""
-    half = dim // 2
-    freqs = jnp.exp(-np.log(max_period) * jnp.arange(half, dtype=jnp.float32)
-                    / half)
-    args = t.astype(jnp.float32)[:, None] * freqs[None]
-    return jnp.concatenate([jnp.cos(args), jnp.sin(args)], axis=-1)
-
 
 def pos_embedding(n_tokens: int, dim: int):
     """1D sincos position embedding over flattened latent tokens."""
@@ -46,32 +40,6 @@ def pos_embedding(n_tokens: int, dim: int):
                     / half)
     args = pos[:, None] * freqs[None]
     return jnp.concatenate([jnp.cos(args), jnp.sin(args)], axis=-1)
-
-
-# ---------------------------------------------------------------------------
-# adaLN-Zero modulate (jnp oracle; Pallas kernel in kernels/adaln.py)
-# ---------------------------------------------------------------------------
-
-def modulate(x, shift, scale):
-    """x: (B, N, D); shift/scale: (B, D)."""
-    return x * (1.0 + scale[:, None]) + shift[:, None]
-
-
-def _mod_norm(x, shift=None, scale=None, *, up: bool = False):
-    """LN (+ shift/scale modulate) — ONE fused HBM pass on the Pallas
-    fast path (DESIGN.md §12), the historic jnp sequence otherwise."""
-    if up:
-        return ops.fused_adaln(x, shift, scale, use_pallas=True)
-    h = _ln(x)
-    return modulate(h, shift, scale) if shift is not None else h
-
-
-def _gated_residual(residual, gate, branch, *, up: bool = False):
-    """residual + gate[:, None] * branch, fused on the Pallas path."""
-    if up:
-        return ops.fused_adaln(branch, gate=gate, residual=residual,
-                               ln=False, use_pallas=True)
-    return residual + gate[:, None] * branch
 
 
 # ---------------------------------------------------------------------------
@@ -98,36 +66,27 @@ def dit_block_apply(p, x, c, txt, cfg: ModelConfig, *, sp_axis=None):
                       p["ada_w"].astype(x.dtype)) + p["ada_b"].astype(x.dtype)
     sh_a, sc_a, g_a, sh_m, sc_m, g_m = jnp.split(mods, 6, axis=-1)
 
-    h = _mod_norm(x, sh_a, sc_a, up=up)
+    h = mod_norm(x, sh_a, sc_a, up=up)
     attn, _ = L.attention_apply(p["attn"], h, cfg, causal=False,
                                 use_rope=False)
-    x = _gated_residual(x, g_a, attn, up=up)
+    x = gated_residual(x, g_a, attn, up=up)
 
     # cross-attention to text conditioning (not modulated, PixArt-style)
-    h = _mod_norm(x, up=up)
+    h = mod_norm(x, up=up)
     ca, _ = L.attention_apply(p["cross"], h, cfg, causal=False, kv_x=txt,
                               use_rope=False)
     x = x + ca
 
-    h = _mod_norm(x, sh_m, sc_m, up=up)
-    x = _gated_residual(x, g_m, L.swiglu_apply(p["mlp"], h), up=up)
+    h = mod_norm(x, sh_m, sc_m, up=up)
+    x = gated_residual(x, g_m, L.swiglu_apply(p["mlp"], h), up=up)
     return x
-
-
-def _ln(x, eps: float = 1e-6):
-    """Parameter-free LayerNorm (adaLN supplies scale/shift)."""
-    dt = x.dtype
-    x = x.astype(jnp.float32)
-    mu = x.mean(-1, keepdims=True)
-    var = ((x - mu) ** 2).mean(-1, keepdims=True)
-    return ((x - mu) * jax.lax.rsqrt(var + eps)).astype(dt)
 
 
 # ---------------------------------------------------------------------------
 # Full DiT
 # ---------------------------------------------------------------------------
 
-def init(key, cfg: ModelConfig):
+def _init(key, cfg: ModelConfig):
     dc = cfg.dit
     d = cfg.d_model
     patch_in = dc.patch_size * dc.patch_size * dc.in_channels
@@ -201,7 +160,7 @@ def forward(params, latents, t, txt_embeds, cfg: ModelConfig, *,
                       params["final_ada_w"].astype(dtype)) \
         + params["final_ada_b"].astype(dtype)
     sh, sc = jnp.split(mods, 2, axis=-1)
-    x = _mod_norm(x, sh, sc, up=ops.use_pallas_enabled(cfg.use_pallas))
+    x = mod_norm(x, sh, sc, up=ops.use_pallas_enabled(cfg.use_pallas))
     x = jnp.einsum("bnd,dp->bnp", x, params["final_out"].astype(dtype))
     return unpatchify(x.astype(jnp.float32), shape, dc.patch_size)
 
@@ -238,32 +197,9 @@ def token_count(cfg: ModelConfig, height: int, width: int,
 # programs run at every SP degree, packed or solo, so the degrees and the
 # packs stay bitwise equal (DESIGN.md §17).
 
-_builds = 0
-_builds_lock = threading.Lock()
-
-
-def _built():
-    """Count one build of a layer program; runs only while tracing."""
-    global _builds
-    with _builds_lock:
-        _builds += 1
-
-
-def builds() -> int:
-    """How many times this process has built (traced) a program of the
-    served step: once per segment and shape, then 0 more once warm."""
-    return _builds
-
-
-def _layer(blocks, i):
-    """Layer ``i`` of the stacked block weights, sliced in the program."""
-    return jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
-        a, i, keepdims=False), blocks)
-
-
 @functools.partial(jax.jit, static_argnames=("cfg", "n_total"))
 def _head(params, tok_shard, t, txt_embeds, pos_offset, *, cfg, n_total):
-    _built()
+    built()
     f32 = jnp.float32
     x = jnp.einsum("bnp,pd->bnd", tok_shard.astype(f32), params["x_embed"])
     pe = pos_embedding(n_total, cfg.d_model)
@@ -280,11 +216,11 @@ def _head(params, tok_shard, t, txt_embeds, pos_offset, *, cfg, n_total):
 @functools.partial(jax.jit, static_argnames=("cfg",))
 def _pre(blocks, i, x, c, *, cfg):
     """Layer ``i`` up to the gather: its modulation rows and q, k, v."""
-    _built()
-    p = _layer(blocks, i)
+    built()
+    p = layer_of(blocks, i)
     mods = jnp.einsum("bd,dk->bk", jax.nn.silu(c), p["ada_w"]) + p["ada_b"]
     sh_a, sc_a, _, _, _, _ = jnp.split(mods, 6, axis=-1)
-    h = _mod_norm(x, sh_a, sc_a, up=cfg.use_pallas)
+    h = mod_norm(x, sh_a, sc_a, up=cfg.use_pallas)
     ap = p["attn"]
     q = jnp.einsum("bsd,dhk->bshk", h, ap["wq"])
     k = jnp.einsum("bsd,dhk->bshk", h, ap["wk"])
@@ -297,26 +233,26 @@ def _post_rest(p, x, mods, txt, attn, cfg: ModelConfig):
     up = cfg.use_pallas
     _, _, g_a, sh_m, sc_m, g_m = jnp.split(mods, 6, axis=-1)
     attn = jnp.einsum("bshk,hkd->bsd", attn, p["attn"]["wo"])
-    x = _gated_residual(x, g_a, attn, up=up)
+    x = gated_residual(x, g_a, attn, up=up)
 
-    h = _mod_norm(x, up=up)
+    h = mod_norm(x, up=up)
     ca, _ = L.attention_apply(p["cross"], h, cfg, causal=False, kv_x=txt,
                               use_rope=False)
     x = x + ca
 
-    h = _mod_norm(x, sh_m, sc_m, up=up)
-    return _gated_residual(x, g_m, L.swiglu_apply(p["mlp"], h), up=up)
+    h = mod_norm(x, sh_m, sc_m, up=up)
+    return gated_residual(x, g_m, L.swiglu_apply(p["mlp"], h), up=up)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
 def _post(blocks, i, x, mods, txt, q, k, v, *, cfg):
     """Layer ``i`` from the gather on: sharded queries over full K/V."""
-    _built()
+    built()
     if cfg.use_pallas:
         attn = ops.attention(q, k, v, causal=False, use_pallas=True)
     else:
         attn = L.sdpa(q, k, v, causal=False)
-    return _post_rest(_layer(blocks, i), x, mods, txt, attn, cfg)
+    return _post_rest(layer_of(blocks, i), x, mods, txt, attn, cfg)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "offset"))
@@ -325,24 +261,77 @@ def _post_spliced(blocks, i, x, mods, txt, q, k_stale, v_stale, k_fresh,
     """``_post`` on a §11 cache hit: the splice kernel patches this
     rank's fresh K/V into the stale snapshot's stream (the fresh rows'
     ``offset`` lays out the kernel's blocks, so it is static)."""
-    _built()
+    built()
     attn = ops.splice_attention(q, k_stale, v_stale, k_fresh, v_fresh,
                                 offset=offset, use_pallas=True)
-    return _post_rest(_layer(blocks, i), x, mods, txt, attn, cfg)
+    return _post_rest(layer_of(blocks, i), x, mods, txt, attn, cfg)
+
+
+def _adaln_layer(blocks, i, x, ctx, kv_gather, layer, cfg):
+    """adaLN block ``i``: pre, the K/V gather, post (or, on a §11 hit
+    the gather hands over as a ``SplicedKV``, the spliced post)."""
+    c, txt = ctx
+    mods, q, k, v = _pre(blocks, i, x, c, cfg=cfg)
+    kv = kv_gather(k, v, layer)                 # GFC all-gather (axis=1)
+    if isinstance(kv, ops.SplicedKV):           # §11 hit, fused splice
+        return _post_spliced(blocks, i, x, mods, txt, q, kv.k_stale,
+                             kv.v_stale, kv.k_fresh, kv.v_fresh, cfg=cfg,
+                             offset=kv.offset)
+    return _post(blocks, i, x, mods, txt, q, *kv, cfg=cfg)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
 def _tail(params, x, c, *, cfg):
-    _built()
+    built()
     mods = jnp.einsum("bd,dk->bk", jax.nn.silu(c), params["final_ada_w"]) \
         + params["final_ada_b"]
     sh, sc = jnp.split(mods, 2, axis=-1)
-    x = _mod_norm(x, sh, sc, up=cfg.use_pallas)
+    x = mod_norm(x, sh, sc, up=cfg.use_pallas)
     return jnp.einsum("bnd,dp->bnp", x, params["final_out"])
 
 
+def _adaln_head(params, tok_shard, t, txt_embeds, cfg, *, pos_offset,
+                n_total, guidance, grids):
+    x, c, txt = _head(params, tok_shard, t, txt_embeds, pos_offset,
+                      cfg=cfg, n_total=n_total)
+    return x, (c, txt)
+
+
+# the adaLN-Zero blocks of PixArt and Wan (one kind, cross-attention to
+# fixed text); FLUX's double- and single-stream blocks in models/flux.py
+FAMILIES = {
+    "adaln": Family(
+        init=_init, head=_adaln_head,
+        kinds=(("adaln", "blocks", _adaln_layer),),
+        counts=lambda cfg: (cfg.num_layers,),
+        tail=lambda params, x, ctx, cfg: _tail(params, x, ctx[0], cfg=cfg),
+        # in the order ``serving/cache_demo.liven`` draws them
+        gate_leaves=(("blocks", "ada_w"), ("blocks", "ada_b"),
+                     ("final_ada_w",), ("final_ada_b",), ("final_out",)),
+        cache_hit=True),
+    "flux": flux.FAMILY,
+}
+
+
+def family(cfg: ModelConfig) -> Family:
+    """The config's block family (``cfg.dit.blocks``)."""
+    return FAMILIES[cfg.dit.blocks]
+
+
+def init(key, cfg: ModelConfig):
+    return family(cfg).init(key, cfg)
+
+
+def segments(cfg: ModelConfig) -> tuple:
+    """The model's block kinds in order, each with its layer count."""
+    fam = family(cfg)
+    return tuple((name, n) for (name, _, _), n in zip(fam.kinds,
+                                                      fam.counts(cfg)))
+
+
 def forward_sp_tokens(params, tok_shard, t, txt_embeds, cfg: ModelConfig, *,
-                      pos_offset: int, n_total: int, kv_gather):
+                      pos_offset: int, n_total: int, kv_gather,
+                      guidance=None, grids=None):
     """Denoiser forward over a TOKEN SHARD under sequence parallelism,
     in float32.
 
@@ -351,16 +340,22 @@ def forward_sp_tokens(params, tok_shard, t, txt_embeds, cfg: ModelConfig, *,
     across the execution group (GFC all-gather in the thread runtime;
     identity at SP1).  Queries stay local, so compute is token-sharded
     while attention sees the full sequence — the paper's elastic SP
-    layout.  The layer index keys the cross-step feature cache
-    (DESIGN.md §11): a cache-hit gather returns the stale remote shards
-    of THIS layer from the previous refresh step with the fresh local
-    shard spliced in, skipping the collective entirely.  On the Pallas
-    fast path the hit gather instead returns a :class:`ops.SplicedKV`
-    and the splice happens inside the attention kernel's K/V stream —
-    the concatenated tensors never materialize (DESIGN.md §12).
+    layout.  The layer index (counted over every block kind) keys the
+    cross-step feature cache (DESIGN.md §11): a cache-hit gather returns
+    the stale remote shards of THIS layer from the previous refresh step
+    with the fresh local shard spliced in, skipping the collective
+    entirely.  On the Pallas fast path the hit gather instead returns a
+    :class:`ops.SplicedKV` and the splice happens inside the attention
+    kernel's K/V stream — the concatenated tensors never materialize
+    (DESIGN.md §12).
 
-    Between gathers the step runs compiled: head, ``pre`` and ``post``
-    per layer, tail (see above); :func:`builds` counts their builds.
+    Between gathers the step runs compiled: the family's head, ``pre``
+    and ``post`` per layer of each of its block kinds (:data:`FAMILIES`),
+    its tail; :func:`builds` counts their builds.  A FLUX model
+    (DESIGN.md §18) also takes ``guidance`` (B,), the scale as an input,
+    and ``grids``, each row's (frames, rows, cols) of patches, which its
+    positions index; its text stream rides in the state, and only image
+    K/V are gathered.
 
     Returns the velocity prediction for the local token shard
     (B, N_local, patch_dim).
@@ -368,16 +363,13 @@ def forward_sp_tokens(params, tok_shard, t, txt_embeds, cfg: ModelConfig, *,
     up = ops.use_pallas_enabled(cfg.use_pallas)
     if cfg.use_pallas != up:
         cfg = cfg.with_(use_pallas=up)
-    blocks = params["blocks"]
-    x, c, txt = _head(params, tok_shard, t, txt_embeds, pos_offset,
-                      cfg=cfg, n_total=n_total)
-    for i in range(jax.tree.leaves(blocks)[0].shape[0]):
-        mods, q, k, v = _pre(blocks, i, x, c, cfg=cfg)
-        kv = kv_gather(k, v, i)                     # GFC all-gather (axis=1)
-        if isinstance(kv, ops.SplicedKV):           # §11 hit, fused splice
-            x = _post_spliced(blocks, i, x, mods, txt, q, kv.k_stale,
-                              kv.v_stale, kv.k_fresh, kv.v_fresh, cfg=cfg,
-                              offset=kv.offset)
-        else:
-            x = _post(blocks, i, x, mods, txt, q, *kv, cfg=cfg)
-    return _tail(params, x, c, cfg=cfg)
+    fam = family(cfg)
+    x, ctx = fam.head(params, tok_shard, t, txt_embeds, cfg,
+                      pos_offset=pos_offset, n_total=n_total,
+                      guidance=guidance, grids=grids)
+    layer = 0
+    for (_, key, step), n in zip(fam.kinds, fam.counts(cfg)):
+        for i in range(n):
+            x = step(params[key], i, x, ctx, kv_gather, layer, cfg)
+            layer += 1
+    return fam.tail(params, x, ctx, cfg)

@@ -99,21 +99,23 @@ def cache_modes(events: list[dict]) -> list[tuple]:
 
 
 def liven(pipeline, seed: int = 123, scale: float = 0.05):
-    """Replace the adaLN-Zero zero-init gates (and the zero output head)
-    with small fixed-seed values.  An untrained DiT gates its attention
+    """Replace the block family's zero-init modulation leaves (adaLN-Zero
+    gates) and output head (its ``gate_leaves``) with small fixed-seed
+    values, drawn in that order.  An untrained DiT gates its attention
     output by exactly zero, so stale-KV reuse would be vacuously exact —
     livening the gates makes the error-budget claim a real measurement
     while keeping every leg of the demo deterministic (same seed, same
     perturbation, every engine)."""
     import jax
+    from repro.models import dit
     key = jax.random.PRNGKey(seed)
-    p = pipeline.dit_params
-    for tree, name in ((p["blocks"], "ada_w"), (p["blocks"], "ada_b"),
-                       (p, "final_ada_w"), (p, "final_ada_b"),
-                       (p, "final_out")):
+    for path in dit.family(pipeline.cfg).gate_leaves:
+        tree = pipeline.dit_params
+        for name in path[:-1]:
+            tree = tree[name]
         key, k = jax.random.split(key)
-        arr = tree[name]
-        tree[name] = scale * jax.random.normal(k, arr.shape, arr.dtype)
+        arr = tree[path[-1]]
+        tree[path[-1]] = scale * jax.random.normal(k, arr.shape, arr.dtype)
 
 
 def run_wall(cfg, reqs, *, cache_interval, shift: bool = True) -> dict:

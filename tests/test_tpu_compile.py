@@ -178,3 +178,71 @@ def test_layer_programs_compile_at_served_width(one_chip, compiled_kernels,
         # second time, beside all the weights the device holds
         assert weights + m.argument_size_in_bytes + m.output_size_in_bytes \
             + m.temp_size_in_bytes < HBM_BYTES
+
+
+# FLUX.1-dev's programs (models/flux.py) at the benchmarked widths and
+# depth (4 double + 8 single blocks of 3072, 24 heads of 128, MLP 12288)
+# at 1024 px: 4,096 image and 512 text tokens, B=1.  The trace names
+# their modules jit__<program>, which the block readers match.
+FLUX_PROGRAMS = {
+    "_flux_head": {"adaln_modulate": False, "flash_attention": False},
+    "_double_pre": {"adaln_modulate": True, "flash_attention": False},
+    "_double_post": {"adaln_modulate": True, "flash_attention": True},
+    "_single_pre": {"adaln_modulate": True, "flash_attention": False},
+    "_single_post": {"adaln_modulate": True, "flash_attention": True},
+    "_flux_tail": {"adaln_modulate": True, "flash_attention": False},
+}
+
+
+@pytest.fixture(scope="module")
+def flux_served():
+    from dataclasses import replace
+    from repro.configs.dit_models import FLUX1_DEV
+    from repro.models import flux
+    from repro.models.layers import split_params
+    cfg = FLUX1_DEV.with_(num_layers=4, use_pallas=True,
+                          dit=replace(FLUX1_DEV.dit, num_single_layers=8))
+    params = jax.eval_shape(
+        lambda: split_params(flux.init(jax.random.PRNGKey(0), cfg))[0])
+    return cfg, params
+
+
+@pytest.mark.parametrize("program", sorted(FLUX_PROGRAMS))
+def test_flux_programs_compile_at_served_width(one_chip, compiled_kernels,
+                                               flux_served, program):
+    from repro.models import flux
+    cfg, params = flux_served
+    weights = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree.leaves(params))
+
+    def on_chip(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    p = jax.tree.map(lambda a: on_chip(a.shape, a.dtype), params)
+    b, n, lt, d = 1, 4096, 512, cfg.d_model
+    s, heads, hd = n + lt, cfg.num_heads, cfg.head_dim
+    i, x, vec = on_chip((), jnp.int32), on_chip((b, s, d)), on_chip((b, d))
+    cos, q = on_chip((b, s, hd // 2)), on_chip((b, s, heads, hd))
+    k_txt, k_img = on_chip((b, lt, heads, hd)), on_chip((b, n, heads, hd))
+    m6, m3 = on_chip((b, 6 * d)), on_chip((b, 3 * d))
+    args = {
+        "_flux_head": (p, on_chip((b, n, 64)), on_chip((b,)), on_chip((b,)),
+                       on_chip((b, lt, cfg.dit.cond_dim)), i),
+        "_double_pre": (p["double"], i, x, vec, cos, cos),
+        "_double_post": (p["double"], i, x, m6, m6, q, k_txt, k_txt, k_img,
+                         k_img),
+        "_single_pre": (p["single"], i, x, vec, cos, cos),
+        "_single_post": (p["single"], i, x, m3, q, on_chip((b, s, cfg.d_ff)),
+                         k_txt, k_txt, k_img, k_img),
+        "_flux_tail": (p, x, vec),
+    }[program]
+    static = {"grids": ((1, 64, 64),)} if program == "_flux_head" else {}
+    compiled = getattr(flux, program).lower(*args, cfg=cfg,
+                                            **static).compile()
+    hlo = compiled.as_text()
+    assert hlo.startswith(f"HloModule jit_{program},")
+    assert _custom_calls(hlo) == {k for k, on in
+                                  FLUX_PROGRAMS[program].items() if on}
+    m = compiled.memory_analysis()
+    assert weights + m.output_size_in_bytes + m.temp_size_in_bytes \
+        < HBM_BYTES
