@@ -4,16 +4,21 @@ Blockwise online-softmax attention over a (batch*head, q-block, k-block)
 grid: the (block_q x d) query tile stays resident while (block_k x d)
 key/value tiles stream through VMEM along the last grid axis, and the
 running max / denominator / accumulator live in VMEM scratch across that
-axis.  VMEM use is a handful of tiles, independent of sequence length.
-MXU alignment: block sizes are multiples of 128 on the token dims and
-head_dim is padded to 128 lanes by the caller if needed (``sm_scale``
-then carries the UNPADDED head dim's softmax scale).
+axis.  The tiles come from the call's shape (:func:`flash_tiles`): the
+largest multiples of 128 that divide the padded lengths, up to 1,280
+rows and within a VMEM budget, since the kernel's time follows its
+number of grid programs.  VMEM use is one such tile's working set,
+independent of sequence length.  head_dim is padded to 128 lanes by the
+caller if needed (``sm_scale`` then carries the UNPADDED head dim's
+softmax scale).
 
 Supports causal masking (k-blocks above the diagonal are neither fetched
 nor computed), GQA (q-head group -> kv-head mapping in the index maps),
 and a static ``kv_valid`` key-validity bound so callers can zero-pad the
-key axis to the block size without the pad keys leaking probability mass
-(k-blocks past ``kv_valid`` are neither fetched nor computed).
+key axis to a multiple of 128 without the pad keys leaking probability
+mass (k-blocks past ``kv_valid`` are neither fetched nor computed).  Only
+the k-block holding ``kv_valid`` and, causal, the blocks crossing the
+diagonal build a mask.
 
 The §11 cache-hit path (DESIGN.md §11-§12) attends against the stale
 snapshot with this rank's fresh shard at rows ``[offset, offset + L)``:
@@ -37,6 +42,46 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+LANE = 128
+# a call's time on a v5e fell with the number of grid programs up to
+# tiles of about 1,024-1,280 rows; 1,536-row tiles ran 15% faster still
+# but cost FLUX's first step some 5 s in every process (PERF.md §6)
+MAX_BLOCK = 1280
+# VMEM that one program's tiles may take, and the scoped limit the
+# compiler is given: a 1,280 x 1,280 float32 tile fills the compiler's
+# default of 16 MiB, and the splice's fresh K/V tiles come on top (a v5e
+# has 128 MiB)
+VMEM_BUDGET = 48 << 20
+VMEM_LIMIT = 64 << 20
+
+
+def _vmem_bytes(block_q: int, block_k: int, d: int, itemsize: int) -> int:
+    """VMEM one program takes: the double-buffered q/o and k/v tiles,
+    the float32 acc and lane-padded m/l scratch, and the float32 score
+    tile with the temporaries of its softmax (s, its exp, p)."""
+    tiles = 2 * (2 * block_q + 2 * block_k) * d * itemsize
+    scratch = (block_q * d + 2 * block_q * LANE) * 4
+    return tiles + scratch + 3 * block_q * block_k * 4
+
+
+def _blocks(n: int, cap: int) -> list[int]:
+    """Multiples of 128 that divide ``n``, up to ``cap``."""
+    return [b for b in range(LANE, min(n, cap) + 1, LANE) if n % b == 0]
+
+
+def flash_tiles(sq: int, sk: int, d: int, itemsize: int) -> tuple[int, int]:
+    """(block_q, block_k) for a call at 128-padded lengths ``sq`` x ``sk``.
+
+    The largest tile (fewest grid programs, then the larger ``block_q``,
+    which streams K/V fewer times) whose blocks divide the lengths, stay
+    under ``MAX_BLOCK`` and fit ``VMEM_BUDGET``.  A length is never
+    padded past its multiple of 128: 128 always divides.
+    """
+    assert sq % LANE == 0 and sk % LANE == 0, (sq, sk)
+    fits = [(bq, bk) for bq in _blocks(sq, MAX_BLOCK)
+            for bk in _blocks(sk, MAX_BLOCK)
+            if _vmem_bytes(bq, bk, d, itemsize) <= VMEM_BUDGET]
+    return max(fits, key=lambda t: (t[0] * t[1], t[0]))
 
 
 def _attn_kernel(*refs, block_q: int, block_k: int, causal: bool,
@@ -66,8 +111,7 @@ def _attn_kernel(*refs, block_q: int, block_k: int, causal: bool,
     if causal:
         run = jnp.logical_and(run, k_start < (q_idx + 1) * block_q)
 
-    @pl.when(run)
-    def _step():
+    def step(masked: bool):
         q = q_ref[...].astype(jnp.float32) * sm_scale
         k = k_ref[...].astype(jnp.float32)
         v = v_ref[...].astype(jnp.float32)
@@ -80,7 +124,7 @@ def _attn_kernel(*refs, block_q: int, block_k: int, causal: bool,
             v = jnp.where(fresh, vf_ref[...].astype(jnp.float32), v)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        if causal or kv_valid % block_k:
+        if masked:
             col = k_start + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
             keep = col < kv_valid
@@ -99,10 +143,32 @@ def _attn_kernel(*refs, block_q: int, block_k: int, causal: bool,
             preferred_element_type=jnp.float32)
         m_sc[...] = m_new
 
+    # only the block holding the kv_valid bound and, causal, the blocks
+    # crossing the diagonal build the mask; every other block skips it
+    edge = None
+    if kv_valid % block_k:
+        edge = k_start + block_k > kv_valid
+    if causal:
+        cross = k_start + block_k - 1 > q_idx * block_q
+        edge = cross if edge is None else jnp.logical_or(edge, cross)
+    if edge is None:
+        pl.when(run)(lambda: step(masked=False))
+    else:
+        pl.when(jnp.logical_and(run, edge))(lambda: step(masked=True))
+        pl.when(jnp.logical_and(run, jnp.logical_not(edge)))(
+            lambda: step(masked=False))
+
     @pl.when(k_idx == pl.num_programs(2) - 1)
     def _finish():
         o_ref[...] = (acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)
                       ).astype(o_ref.dtype)
+
+
+def _tiles(q, k, block_q, block_k):
+    """The blocks a call asked for, else :func:`flash_tiles`'s."""
+    tq, tk = flash_tiles(q.shape[1], k.shape[1], q.shape[3],
+                         q.dtype.itemsize)
+    return block_q or tq, block_k or tk
 
 
 def _fold_heads(x):
@@ -161,7 +227,8 @@ def _flash_call(q, k, v, fresh, *, causal, block_q, block_k, sm_scale,
                         pltpu.VMEM((block_q, 1), jnp.float32),
                         pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
         # the op's name in a profiler trace (flash_attention.N), for the
         # splice path too, whatever wrapper calls it
@@ -173,16 +240,19 @@ def _flash_call(q, k, v, fresh, *, causal, block_q, block_k, sm_scale,
 @functools.partial(
     jax.jit, static_argnames=("causal", "block_q", "block_k", "sm_scale",
                               "kv_valid", "interpret"))
-def flash_attention(q, k, v, *, causal: bool = False, block_q: int = 128,
-                    block_k: int = 128, sm_scale: float | None = None,
+def flash_attention(q, k, v, *, causal: bool = False,
+                    block_q: int | None = None, block_k: int | None = None,
+                    sm_scale: float | None = None,
                     kv_valid: int | None = None, interpret: bool = True):
     """q: (B, Sq, H, d); k/v: (B, Sk, KV, d) with H % KV == 0.
 
-    Returns (B, Sq, H, d).  Sq/Sk must be multiples of the block sizes
-    (kernels/ops.py pads, passing ``kv_valid`` = the true key count so
-    pad keys are masked out); d should be MXU-aligned (128) for peak
-    throughput — zero-pad d and pass ``sm_scale`` for the original dim.
+    Returns (B, Sq, H, d).  Sq/Sk must be multiples of 128 and of the
+    block sizes, which default to :func:`flash_tiles`'s (kernels/ops.py
+    pads, passing ``kv_valid`` = the true key count so pad keys are
+    masked out); d should be MXU-aligned (128) for peak throughput —
+    zero-pad d and pass ``sm_scale`` for the original dim.
     """
+    block_q, block_k = _tiles(q, k, block_q, block_k)
     return _flash_call(q, k, v, None, causal=causal, block_q=block_q,
                        block_k=block_k, sm_scale=sm_scale,
                        kv_valid=kv_valid, fresh_rows=None,
@@ -193,19 +263,22 @@ def flash_attention(q, k, v, *, causal: bool = False, block_q: int = 128,
     jax.jit, static_argnames=("offset", "block_q", "block_k", "sm_scale",
                               "kv_valid", "interpret"))
 def splice_attention(q, k_stale, v_stale, k_fresh, v_fresh, *, offset: int,
-                     block_q: int = 128, block_k: int = 128,
+                     block_q: int | None = None,
+                     block_k: int | None = None,
                      sm_scale: float | None = None,
                      kv_valid: int | None = None, interpret: bool = True):
     """Attention over splice(stale, fresh @ offset), never materialized.
 
     q: (B, Sq, H, d); k_stale/v_stale: (B, Sk, KV, d);
     k_fresh/v_fresh: (B, L, KV, d) with offset + L <= kv_valid <= Sk.
-    Non-causal (the DiT denoise path).  Sq/Sk must be multiples of the
-    block sizes (kernels/ops.py pads and passes ``kv_valid``).
+    Non-causal (the DiT denoise path).  Sq/Sk must be multiples of 128
+    and of the block sizes, which default to :func:`flash_tiles`'s
+    (kernels/ops.py pads and passes ``kv_valid``).
     """
     sk = k_stale.shape[1]
     local_len = k_fresh.shape[1]
     kv_valid = sk if kv_valid is None else kv_valid
+    block_q, block_k = _tiles(q, k_stale, block_q, block_k)
     assert 0 <= offset and offset + local_len <= kv_valid <= sk, \
         (offset, local_len, kv_valid, sk)
     # lay the fresh shard out on the stale stream's block grid: window

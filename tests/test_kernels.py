@@ -7,7 +7,8 @@ import pytest
 
 from repro.kernels import ops, ref
 from repro.kernels.adaln import adaln_modulate
-from repro.kernels.flash_attention import flash_attention
+from repro.kernels import flash_attention as fa
+from repro.kernels.flash_attention import flash_attention, flash_tiles
 from repro.kernels.ssd import ssd_scan
 
 KEY = jax.random.PRNGKey(42)
@@ -34,6 +35,58 @@ def test_flash_attention_sweep(b, sq, sk, h, kv, d, causal, dtype):
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32),
                                atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("sq,sk,tiles", [
+    (4096, 4096, (1024, 1024)),     # image-batch-1c self-attention
+    (4096, 128, (1024, 128)),       # image-batch-1c cross-attention
+    (11520, 11520, (1280, 1280)),   # video-batch-1c, 11,440 rows padded
+    (11520, 128, (1280, 128)),      # video-batch-1c cross-attention
+    (4608, 4608, (1152, 1152)),     # flux-batch-1c joint attention
+    (2176, 2176, (128, 128)),       # 17 x 128: no other divisor fits
+    (128, 256, (128, 256)),
+])
+def test_flash_tiles(sq, sk, tiles):
+    """The largest 128-multiple blocks dividing each padded length, up to
+    the cap and within the VMEM budget; never a pad past 128."""
+    block_q, block_k = flash_tiles(sq, sk, 128, 4)
+    assert (block_q, block_k) == tiles
+    assert sq % block_q == 0 and sk % block_k == 0
+    assert fa._vmem_bytes(block_q, block_k, 128, 4) <= fa.VMEM_BUDGET
+
+
+def test_flash_tiles_respect_vmem_budget():
+    """A wide head shrinks the tile to what the budget holds."""
+    block_q, block_k = flash_tiles(4608, 4608, 1024, 4)
+    assert fa._vmem_bytes(block_q, block_k, 1024, 4) <= fa.VMEM_BUDGET
+    assert block_q * block_k < 1152 * 1152
+    assert fa._vmem_bytes(1152, 1152, 1024, 4) > fa.VMEM_BUDGET
+
+
+@pytest.mark.parametrize("sq,sk,block_q,block_k,kv_valid,causal", [
+    (768, 1280, 384, 640, 1230, False),     # pad keys in the last block only
+    (768, 1280, 384, 640, 1280, False),     # no pad keys: no block masks
+    (1280, 1280, 640, 256, 1000, False),    # pad keys from a middle block on
+    (768, 768, 256, 384, 768, True),        # block_q < block_k
+    (768, 768, 384, 128, 768, True),        # block_q > block_k
+    (768, 768, 384, 256, 700, True),        # diagonal and pad keys
+])
+def test_flash_attention_tiles(sq, sk, block_q, block_k, kv_valid, causal):
+    """Tiles that are not powers of two, or not square, give the oracle's
+    attention over the first ``kv_valid`` keys; the keys past it are
+    filled with huge values, so any mass they got would show."""
+    ks = jax.random.split(KEY, 3)
+    q = jax.random.normal(ks[0], (1, sq, 2, 64))
+    k = jax.random.normal(ks[1], (1, sk, 2, 64)).at[:, kv_valid:].set(1e6)
+    v = jax.random.normal(ks[2], (1, sk, 2, 64)).at[:, kv_valid:].set(1e6)
+    out = flash_attention(q, k, v, causal=causal, block_q=block_q,
+                          block_k=block_k, kv_valid=kv_valid)
+    if causal:
+        out, q = out[:, :kv_valid], q[:, :kv_valid]
+    want = ref.attention_ref(q, k[:, :kv_valid], v[:, :kv_valid],
+                             causal=causal)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               atol=1e-5, rtol=1e-5)
 
 
 @pytest.mark.parametrize("b,n,d", [(1, 128, 64), (2, 256, 128),
@@ -138,6 +191,30 @@ def test_splice_attention_gqa_odd_head_dim():
     out = ops.splice_attention(q, k_st, v_st, k_fr, v_fr, offset=off,
                                use_pallas=True)
     want = ref.splice_attention_ref(q, k_st, v_st, k_fr, v_fr, offset=off)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("n_total,offset,local", [
+    (3072, 900, 300),       # 1,024-row blocks: the shard straddles two
+    (3000, 2000, 800),      # padded to 3,072: pad keys after the shard
+    (1024, 256, 256),       # one 1,024 x 1,024 tile, an aligned shard
+])
+def test_splice_attention_rule_tiles(n_total, offset, local):
+    """§11 splice at the tiles ``flash_tiles`` picks, larger than 128."""
+    padded = -(-n_total // 128) * 128
+    assert min(flash_tiles(padded, padded, 64, 4)) > 128
+    ks = jax.random.split(KEY, 5)
+    b, h, d = 1, 2, 64
+    q = jax.random.normal(ks[0], (b, n_total, h, d))
+    k_st = jax.random.normal(ks[1], (b, n_total, h, d))
+    v_st = jax.random.normal(ks[2], (b, n_total, h, d))
+    k_fr = jax.random.normal(ks[3], (b, local, h, d))
+    v_fr = jax.random.normal(ks[4], (b, local, h, d))
+    out = ops.splice_attention(q, k_st, v_st, k_fr, v_fr, offset=offset,
+                               use_pallas=True)
+    want = ref.splice_attention_ref(q, k_st, v_st, k_fr, v_fr,
+                                    offset=offset)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                atol=1e-5, rtol=1e-5)
 

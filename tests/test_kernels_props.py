@@ -125,10 +125,10 @@ def test_attention_pad_keys_carry_no_mass():
     q, k, v = _qkv(128, 256, 2, 2, 64, jnp.float32)
     # valid=100: rows 100-127 exercise the partial-block mask, rows
     # 128-255 exercise block skipping (that block is never visited)
-    clean = flash_attention(q, k, v, kv_valid=100)
+    clean = flash_attention(q, k, v, kv_valid=100, block_k=128)
     kw = k.at[:, 100:].set(1e6)
     vw = v.at[:, 100:].set(1e6)
-    dirty = flash_attention(q, kw, vw, kv_valid=100)
+    dirty = flash_attention(q, kw, vw, kv_valid=100, block_k=128)
     np.testing.assert_array_equal(np.asarray(clean), np.asarray(dirty))
     want = ref.attention_ref(q, k[:, :100], v[:, :100], causal=False)
     np.testing.assert_allclose(np.asarray(clean), np.asarray(want),

@@ -73,6 +73,19 @@ def test_flash_attention_compiles(one_chip, compiled_kernels, n_q, n_kv):
              (1, n_kv, HEADS, HEAD_DIM), (1, n_kv, HEADS, HEAD_DIM))
 
 
+@pytest.mark.parametrize("b,n,h,d", [
+    (2, 4096, 16, 72),      # image-batch-1c: guided PixArt, d padded to 128
+    (1, 11440, 24, 128),    # video-batch-1c: padded to 11,520 rows
+    (1, 4608, 24, 128),     # flux-batch-1c: 4,096 image + 512 text rows
+], ids=["image", "video", "flux"])
+def test_flash_attention_compiles_at_cell_tiles(one_chip, compiled_kernels,
+                                                b, n, h, d):
+    """Self-attention at each benchmark cell's shape, with the tiles
+    ``flash_tiles`` picks there: a tile past the VMEM limit fails here."""
+    _compile(lambda q, k, v: ops.attention(q, k, v, use_pallas=True),
+             one_chip, "flash_attention", *[(b, n, h, d)] * 3)
+
+
 @pytest.mark.parametrize("batch", [1, 2])    # B=2: batched CFG and packs
 def test_adaln_full_fusion_compiles(one_chip, compiled_kernels, batch):
     n = 4096
