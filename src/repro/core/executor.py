@@ -47,11 +47,13 @@ class ThreadBackend:
     """One worker thread per rank + a completion queue.
 
     ``adapter`` must provide
-        execute(task, layout, rank, comm, graph) -> None
-    which runs this rank's share of the task (GFC rendezvous inside) and,
-    on the leader rank, installs output artifact data — and, for step
-    packing, ``execute_packed(members, layout, rank, comm, desc)`` which
-    runs the stacked batch as ONE model call.
+        execute(task, layout, rank, comm, graph, desc) -> None
+    which runs this rank's share of the task (GFC rendezvous inside,
+    over the dispatch's descriptor ``desc``) and installs its output
+    artifact data — and, for step packing,
+    ``execute_packed(members, layout, rank, comm, desc)`` which runs the
+    members' denoise steps as ONE model call.  ``DiTPipeline`` runs a
+    solo denoise step through the same path, as a pack of one.
     """
 
     def __init__(self, adapter, num_ranks: int,

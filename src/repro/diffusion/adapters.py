@@ -6,8 +6,12 @@ The converter records each denoise task's exact token count in
 ``task.meta["tokens"]``; together with the request's model name it forms
 the *pack signature* (``core/scheduler.py::pack_signature``) that
 decides which denoise steps may share one batched executor call
-(DESIGN.md §9 step packing).  Executors that support packing expose
-``execute_packed`` next to ``execute`` (see ``diffusion/pipeline.py``).
+(DESIGN.md §9 step packing).  Every denoise task and the decode task
+also carry the latent's ``(F, H, W, C)`` in ``task.meta["latent_shape"]``,
+the one place the executors read it from.  The pipeline
+(``diffusion/pipeline.py``) exposes ``execute_packed`` next to
+``execute`` and runs both through one step, a solo step being a pack of
+one.
 """
 from __future__ import annotations
 
@@ -38,6 +42,7 @@ def convert_request(req: Request, cfg: ModelConfig) -> RequestGraph:
     f = req.frames
     f_lat = max(1, (f + 3) // 4) if f > 1 else 1
     h_lat, w_lat = req.height // 8, req.width // 8
+    lat_shape = (f_lat, h_lat, w_lat, dc.in_channels)
     n_tok = f_lat * (h_lat // dc.patch_size) * (w_lat // dc.patch_size)
     patch_dim = dc.patch_size * dc.patch_size * dc.in_channels
 
@@ -83,8 +88,7 @@ def convert_request(req: Request, cfg: ModelConfig) -> RequestGraph:
                            inputs=[txt.id, prev_latent.id],
                            outputs=[nxt.id],
                            meta={"tokens": n_tok, "step": step,
-                                 "latent_shape": (f_lat, h_lat, w_lat,
-                                                  dc.in_channels)})
+                                 "latent_shape": lat_shape})
         tasks[t.id] = t
         prev_latent = nxt
 
@@ -114,7 +118,7 @@ def convert_request(req: Request, cfg: ModelConfig) -> RequestGraph:
     dec = TrajectoryTask(id=fresh_id("task"), request_id=req.id,
                          kind="decode", inputs=[prev_latent.id],
                          outputs=[out.id],
-                         meta={"tokens": n_tok})
+                         meta={"tokens": n_tok, "latent_shape": lat_shape})
     tasks[dec.id] = dec
     req.task_ids = list(tasks)
     return RequestGraph(request=req, tasks=tasks, artifacts=artifacts)

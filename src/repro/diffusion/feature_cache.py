@@ -56,9 +56,10 @@ def cache_artifact(graph: RequestGraph):
 def snapshot_kv(stores: list, layer: int) -> tuple[np.ndarray, np.ndarray]:
     """Stack the per-member stale K/V snapshots for ``layer`` into fresh
     (B, N_total, H, hd) arrays — the §11 hit path's batched view of the
-    storage layout this module owns.  ``np.stack`` copies, so executors
-    may splice rows in place (the jnp path) or hand the arrays to the
-    fused splice kernel untouched (the Pallas path, DESIGN.md §12)."""
+    storage layout this module owns.  The pipeline hands them, beside
+    the step's fresh shard, to the model as an ``ops.SplicedKV``, and
+    the model splices (DESIGN.md §12); the stored snapshots stay as the
+    last refresh wrote them."""
     K = np.stack([s[f"k{layer}"] for s in stores])
     V = np.stack([s[f"v{layer}"] for s in stores])
     return K, V

@@ -11,7 +11,6 @@ from __future__ import annotations
 import contextlib
 import hashlib
 import threading
-from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
@@ -98,6 +97,15 @@ class DiTPipeline:
     def _sigmas(self, steps: int):
         return schedule.flow_sigmas(steps, self.cfg.dit.flow_shift)
 
+    def _schedule(self, task, req):
+        """(timestep, sigma now, sigma next) of a denoise task's step;
+        the last step lands on sigma 0."""
+        sigmas = self._sigmas(req.steps)
+        step = task.meta["step"]
+        s_now = float(sigmas[step])
+        s_next = float(sigmas[step + 1]) if step + 1 < req.steps else 0.0
+        return schedule.timestep_of_sigma(s_now), s_now, s_next
+
     def _scales(self, reqs):
         """Each row's guidance scale, where the model takes it as an input
         (None otherwise); an unguided request runs at scale 1."""
@@ -122,100 +130,124 @@ class DiTPipeline:
             if rank == layout.ranks[0]:
                 self._encode(task, layout, rank, graph)
         elif task.kind == "denoise":
-            self._denoise(task, layout, rank, comm, graph, desc)
+            self._step([(task, graph)], layout, rank, comm, desc)
         elif task.kind == "decode":
             if rank == layout.ranks[0]:
                 self._decode(task, layout, rank, graph)
         else:
             raise ValueError(task.kind)
 
-    # ------------------------------------------------------------------
     def execute_packed(self, members, layout: ExecutionLayout, rank: int,
                        comm: GroupFreeComm, desc: GroupDescriptor):
-        """Step packing (DESIGN.md §9): run this rank's share of N
-        batch-compatible denoise tasks as ONE batched forward.
+        """Step packing (DESIGN.md §9): this rank's share of N
+        batch-compatible denoise tasks as ONE batched forward (the
+        control plane guarantees identical token shapes), with one set of
+        GFC collectives amortized over the pack."""
+        self._step(members, layout, rank, comm, desc)
 
-        Latent shards are stacked along the batch axis (the control plane
-        guarantees identical token shapes), per-member sigmas ride the
-        batched timestep vector, and the SP KV all-gather runs ONCE over
-        the stacked tensors — one set of GFC collectives amortized over
-        the pack.  Each member's Euler update then uses its own sigma
-        pair, and outputs land in per-request artifacts (no cross-request
-        state is shared beyond the stacked forward)."""
-        xs, txts, t_steps, sig_pairs = [], [], [], []
-        for task, graph in members:
-            req = graph.request
-            txts.append(graph.artifacts[task.inputs[0]].data[rank]["embeds"])
-            xs.append(graph.artifacts[task.inputs[1]].data[rank]["latent"])
-            sigmas = self._sigmas(req.steps)
-            step = task.meta["step"]
-            s_now = float(sigmas[step])
-            s_next = (float(sigmas[step + 1]) if step + 1 < req.steps
-                      else 0.0)
-            sig_pairs.append((s_now, s_next))
-            t_steps.append(schedule.timestep_of_sigma(s_now))
+    # ------------------------------------------------------------------
+    def _step(self, members, layout, rank, comm, desc):
+        """One denoise step of ``members`` ([(task, graph)]; a solo step
+        is a pack of one) on this rank's token shard, as one forward.
 
+        Each member contributes the rows this rank runs: [cond, uncond]
+        for a guided request on a ``cfg == 1`` layout, its branch's row
+        under a CFG split (DESIGN.md §14), else one row.  A member's
+        latent shard and embeddings cross to the device once.  After the
+        forward a guided member merges its two velocities (under a split,
+        through the one exchange with its branch peer over
+        ``desc.merge``), then each member takes its Euler update with its
+        own sigma pair into its own output artifact."""
         task0, graph0 = members[0]
         spec = graph0.artifacts[task0.inputs[1]].fields["latent"]
-        view = field_view(spec, layout)
-        off, size = view.slices[rank]
-        n_total = spec.global_shape[0]
-
-        stamp = task0.meta.get("cache")
-        if layout.degree == 1:
-            def kv_gather(k, v, layer):
-                return k, v
-        elif stamp is None:
-            def kv_gather(k, v, layer):
-                K = comm.all_gather(desc, rank, np.asarray(k), axis=1)
-                V = comm.all_gather(desc, rank, np.asarray(v), axis=1)
-                return jnp.asarray(K), jnp.asarray(V)
-        else:
-            # cross-step feature cache (DESIGN.md §11): the pack shares
-            # ONE plane-stamped decision; per-member snapshots live in
-            # each member's kv_cache artifact, batch rows map to members
-            stores = [g.artifacts[tk.meta["cache"]["art"]].data[rank]
-                      for tk, g in members]
-            if stamp["mode"] == "refresh":
-                def kv_gather(k, v, layer):
-                    K = comm.all_gather(desc, rank, np.asarray(k), axis=1)
-                    V = comm.all_gather(desc, rank, np.asarray(v), axis=1)
-                    for j, store in enumerate(stores):
-                        store[f"k{layer}"] = K[j]
-                        store[f"v{layer}"] = V[j]
-                    return jnp.asarray(K), jnp.asarray(V)
-            elif ops.use_pallas_enabled(self.cfg.use_pallas):
-                # fast path: hand the stale snapshot + fresh shard to
-                # the fused splice kernel — no materialized concat
-                def kv_gather(k, v, layer):
-                    K, V = snapshot_kv(stores, layer)
-                    return ops.SplicedKV(jnp.asarray(K), jnp.asarray(V),
-                                         k, v, int(off))
-            else:
-                def kv_gather(k, v, layer):
-                    K, V = snapshot_kv(stores, layer)
-                    K[:, off:off + size] = np.asarray(k)
-                    V[:, off:off + size] = np.asarray(v)
-                    return jnp.asarray(K), jnp.asarray(V)
+        off, _ = field_view(spec, layout).slices[rank]
+        branch = layout.branch_of(rank) if layout.cfg > 1 else None
+        group = desc if branch is None else desc.branches[branch]
+        rows_of, sched, t_rows, reqs, grids = [], [], [], [], []
+        for task, graph in members:
+            req = graph.request
+            names = ("embeds", "embeds_uncond")[:req.cfg_branches]
+            rows = names if branch is None else names[branch:branch + 1]
+            t, s_now, s_next = self._schedule(task, req)
+            rows_of.append(rows)
+            sched.append((s_now, s_next))
+            t_rows += [t] * len(rows)
+            reqs += [req] * len(rows)
+            grids += [self._grid(task)] * len(rows)
 
         with self._region("gfdit.step.inputs"):
-            x = jnp.stack([jnp.asarray(s) for s in xs])    # (B, N_loc, pd)
-            txt = jnp.stack([jnp.asarray(s) for s in txts])  # (B, Lt, cond)
-            t = jnp.array(t_steps, jnp.float32)
-        v = self._forward(rank, x, t, txt, off, n_total, kv_gather,
-                          guidance=self._scales([g.request
-                                                 for _, g in members]),
-                          grids=tuple(self._grid(tk) for tk, _ in members))
+            xs, x_rows, txt_rows = [], [], []
+            for (task, graph), rows in zip(members, rows_of):
+                x = jnp.asarray(graph.artifacts[task.inputs[1]]
+                                .data[rank]["latent"])      # (N_loc, pd)
+                embeds = graph.artifacts[task.inputs[0]].data[rank]
+                xs.append(x)
+                x_rows += [x] * len(rows)
+                txt_rows += [jnp.asarray(embeds[r]) for r in rows]
+            x_in = jnp.stack(x_rows)                       # (B, N_loc, pd)
+            txt = jnp.stack(txt_rows)                      # (B, Lt, cond)
+            t_in = jnp.array(t_rows, jnp.float32)
+        v = self._forward(rank, x_in, t_in, txt, off, spec.global_shape[0],
+                          self._kv_gather(members, layout, rank, comm,
+                                          group, off),
+                          guidance=self._scales(reqs), grids=tuple(grids))
+
         with self._region("gfdit.step.update"):
-            new = [schedule.flow_step(x[i], v[i], s_now, s_next)
-                   for i, (s_now, s_next) in enumerate(sig_pairs)]
+            new, i = [], 0
+            for (_, graph), x, rows, (s_now, s_next) in zip(
+                    members, xs, rows_of, sched):
+                vel = v[i]
+                if graph.request.cfg_branches == 2:
+                    if branch is None:
+                        v_c, v_u = vel, v[i + 1]
+                    else:
+                        # the one guidance-merge exchange: branch peers
+                        # sharing this token slice swap velocity shards;
+                        # merge-group order is branch order (cond, uncond)
+                        both = comm.all_gather(
+                            desc.merge[group.local_index(rank)], rank,
+                            np.asarray(vel)[None], axis=0)
+                        v_c, v_u = jnp.asarray(both[0]), jnp.asarray(both[1])
+                    vel = v_u + float(graph.request.guidance) * (v_c - v_u)
+                new.append(schedule.flow_step(x, vel, s_now, s_next))
+                i += len(rows)
         with self._region("gfdit.step.fetch",
                           bytes=sum(n.nbytes for n in new)):
             for (task, graph), new_x, (_, s_next) in zip(members, new,
-                                                         sig_pairs):
-                out_art = graph.artifacts[task.outputs[0]]
-                out_art.data[rank]["latent"] = np.asarray(new_x)
-                out_art.data[rank]["sigma"] = np.float32(s_next)
+                                                         sched):
+                out = graph.artifacts[task.outputs[0]].data[rank]
+                out["latent"] = np.asarray(new_x)
+                out["sigma"] = np.float32(s_next)
+
+    def _kv_gather(self, members, layout, rank, comm, group, off):
+        """The step's per-layer K/V gather over ``group`` (the whole
+        layout, or this rank's CFG branch): none with one rank in it; the
+        GFC all-gather; under a §11 refresh stamp, the gather that also
+        stores each member's snapshot; on a hit, the stale snapshots with
+        this step's fresh shard beside them as a :class:`ops.SplicedKV`,
+        which the model splices (DESIGN.md §11, §12).  The stamp is the
+        pack's one plane-made decision."""
+        if layout.sp == 1:
+            return lambda k, v, layer: (k, v)
+        stamp = members[0][0].meta.get("cache")
+        stores = [] if stamp is None else [
+            g.artifacts[tk.meta["cache"]["art"]].data[rank]
+            for tk, g in members]
+
+        def gather(k, v, layer):
+            K = comm.all_gather(group, rank, np.asarray(k), axis=1)
+            V = comm.all_gather(group, rank, np.asarray(v), axis=1)
+            # a refresh snapshots the gathered bytes themselves, so it is
+            # bit-exact with an uncached step; every rank stores the same
+            for store, k_j, v_j in zip(stores, K, V):
+                store[f"k{layer}"], store[f"v{layer}"] = k_j, v_j
+            return jnp.asarray(K), jnp.asarray(V)
+
+        def hit(k, v, layer):
+            K, V = snapshot_kv(stores, layer)
+            return ops.SplicedKV(jnp.asarray(K), jnp.asarray(V), k, v, off)
+        return hit if stamp is not None and stamp["mode"] == "hit" \
+            else gather
 
     # ------------------------------------------------------------------
     def _encode(self, task, layout, rank, graph):
@@ -258,175 +290,6 @@ class DiTPipeline:
             lat_art.data[r]["sigma"] = np.float32(sigmas[0])
 
     # ------------------------------------------------------------------
-    def _denoise(self, task, layout, rank, comm, graph, desc):
-        req = graph.request
-        if req.cfg_branches == 2:
-            return self._denoise_guided(task, layout, rank, comm, graph,
-                                        desc)
-        txt_art = graph.artifacts[task.inputs[0]]
-        lat_art = graph.artifacts[task.inputs[1]]
-        out_art = graph.artifacts[task.outputs[0]]
-        txt = txt_art.data[rank]["embeds"]
-        x_shard = lat_art.data[rank]["latent"]                 # (N_loc, pd)
-        spec = lat_art.fields["latent"]
-        view = field_view(spec, layout)
-        off, size = view.slices[rank]
-        n_total = spec.global_shape[0]
-
-        sigmas = self._sigmas(req.steps)
-        step = task.meta["step"]
-        sigma_now = float(sigmas[step])
-        sigma_next = float(sigmas[step + 1]) if step + 1 < req.steps else 0.0
-
-        stamp = task.meta.get("cache")
-        if layout.degree == 1:
-            def kv_gather(k, v, layer):
-                return k, v
-        elif stamp is None:
-            def kv_gather(k, v, layer):
-                K = comm.all_gather(desc, rank, np.asarray(k), axis=1)
-                V = comm.all_gather(desc, rank, np.asarray(v), axis=1)
-                return jnp.asarray(K), jnp.asarray(V)
-        elif stamp["mode"] == "refresh":
-            # full gather; snapshot this rank's copy per layer — every
-            # rank stores the SAME gathered bytes (replicated fields),
-            # and the returned arrays are exactly the uncached ones, so
-            # a refresh step is bit-exact with the non-cached path
-            store = graph.artifacts[stamp["art"]].data[rank]
-
-            def kv_gather(k, v, layer):
-                K = comm.all_gather(desc, rank, np.asarray(k), axis=1)
-                V = comm.all_gather(desc, rank, np.asarray(v), axis=1)
-                store[f"k{layer}"] = K[0]
-                store[f"v{layer}"] = V[0]
-                return jnp.asarray(K), jnp.asarray(V)
-        elif ops.use_pallas_enabled(self.cfg.use_pallas):
-            # cache hit on the Pallas fast path: the stale snapshot and
-            # the fresh local shard go to the fused splice kernel, which
-            # patches the K/V stream in-register (DESIGN.md §12) — no
-            # collective AND no materialized concat
-            store = graph.artifacts[stamp["art"]].data[rank]
-
-            def kv_gather(k, v, layer):
-                K, V = snapshot_kv([store], layer)
-                return ops.SplicedKV(jnp.asarray(K), jnp.asarray(V),
-                                     k, v, int(off))
-        else:
-            # cache hit: stale remote shards from the last refresh, with
-            # THIS step's fresh local K/V spliced in — no collective
-            store = graph.artifacts[stamp["art"]].data[rank]
-
-            def kv_gather(k, v, layer):
-                K, V = snapshot_kv([store], layer)
-                K[:, off:off + size] = np.asarray(k)
-                V[:, off:off + size] = np.asarray(v)
-                return jnp.asarray(K), jnp.asarray(V)
-
-        with self._region("gfdit.step.inputs"):
-            x = jnp.asarray(x_shard)
-            txt = jnp.asarray(txt)
-            t = jnp.array([schedule.timestep_of_sigma(sigma_now)],
-                          jnp.float32)
-        v_shard = self._forward(rank, x[None], t, txt[None], off, n_total,
-                                kv_gather, guidance=self._scales([req]),
-                                grids=(self._grid(task),))[0]
-        with self._region("gfdit.step.update"):
-            new_x = schedule.flow_step(x, v_shard, sigma_now, sigma_next)
-        with self._region("gfdit.step.fetch", bytes=new_x.nbytes):
-            out_art.data[rank]["latent"] = np.asarray(new_x)
-        out_art.data[rank]["sigma"] = np.float32(sigma_next)
-
-    # ------------------------------------------------------------------
-    def _denoise_guided(self, task, layout, rank, comm, graph, desc):
-        """Classifier-free guidance denoise (DESIGN.md §14).
-
-        ``cfg == 1``: ONE batched forward with rows [cond, uncond] on the
-        whole group (the historic single-group batched-CFG path).
-        ``cfg >= 2``: this rank's branch runs its row B=1 with SP
-        collectives confined to the branch descriptor, then ONE merge
-        exchange joins branch peers holding the same token slice; every
-        peer computes the identical merged velocity, so branch shards
-        stay replicated across the CFG dimension — bit-exact versus the
-        batched path at the same shard size (asserted in
-        serving/hybrid_demo.py).  Guided steps bypass the §11 feature
-        cache (branch-specific KV cannot share a replicated snapshot).
-        """
-        req = graph.request
-        g = float(req.guidance)
-        txt_art = graph.artifacts[task.inputs[0]]
-        lat_art = graph.artifacts[task.inputs[1]]
-        out_art = graph.artifacts[task.outputs[0]]
-        txt_c = txt_art.data[rank]["embeds"]
-        txt_u = txt_art.data[rank]["embeds_uncond"]
-        x_shard = lat_art.data[rank]["latent"]              # (N_loc, pd)
-        spec = lat_art.fields["latent"]
-        view = field_view(spec, layout)
-        off, _ = view.slices[rank]
-        n_total = spec.global_shape[0]
-
-        sigmas = self._sigmas(req.steps)
-        step = task.meta["step"]
-        sigma_now = float(sigmas[step])
-        sigma_next = float(sigmas[step + 1]) if step + 1 < req.steps \
-            else 0.0
-        ts = schedule.timestep_of_sigma(sigma_now)
-
-        if layout.cfg == 1:
-            if layout.degree == 1:
-                def kv_gather(k, v, layer):
-                    return k, v
-            else:
-                def kv_gather(k, v, layer):
-                    K = comm.all_gather(desc, rank, np.asarray(k), axis=1)
-                    V = comm.all_gather(desc, rank, np.asarray(v), axis=1)
-                    return jnp.asarray(K), jnp.asarray(V)
-            with self._region("gfdit.step.inputs"):
-                x = jnp.asarray(x_shard)
-                rows = jnp.stack([x, x])
-                txt = jnp.stack([jnp.asarray(txt_c), jnp.asarray(txt_u)])
-                t = jnp.array([ts, ts], jnp.float32)
-            v = self._forward(rank, rows, t, txt, off, n_total, kv_gather,
-                              grids=(self._grid(task),) * 2)
-        else:
-            b = layout.branch_of(rank)
-            branch = desc.branches[b]
-            i_local = branch.local_index(rank)
-            merge = desc.merge[i_local]
-            if layout.sp == 1:
-                def kv_gather(k, v, layer):
-                    return k, v
-            else:
-                def kv_gather(k, v, layer):
-                    K = comm.all_gather(branch, rank, np.asarray(k),
-                                        axis=1)
-                    V = comm.all_gather(branch, rank, np.asarray(v),
-                                        axis=1)
-                    return jnp.asarray(K), jnp.asarray(V)
-            with self._region("gfdit.step.inputs"):
-                x = jnp.asarray(x_shard)
-                txt = jnp.asarray(txt_c if b == 0 else txt_u)
-                t = jnp.array([ts], jnp.float32)
-            v_mine = self._forward(rank, x[None], t, txt[None], off,
-                                   n_total, kv_gather,
-                                   grids=(self._grid(task),))[0]
-        with self._region("gfdit.step.update"):
-            if layout.cfg == 1:
-                v_c, v_u = v[0], v[1]
-            else:
-                # the one guidance-merge exchange: branch peers sharing
-                # this token slice swap velocity shards; merge-group rank
-                # order is branch order, so parts[0]=cond, parts[1]=uncond
-                both = comm.all_gather(merge, rank,
-                                       np.asarray(v_mine)[None], axis=0)
-                v_c, v_u = both[0], both[1]
-            merged = jnp.asarray(v_u) + g * (jnp.asarray(v_c)
-                                             - jnp.asarray(v_u))
-            new_x = schedule.flow_step(x, merged, sigma_now, sigma_next)
-        with self._region("gfdit.step.fetch", bytes=new_x.nbytes):
-            out_art.data[rank]["latent"] = np.asarray(new_x)
-        out_art.data[rank]["sigma"] = np.float32(sigma_next)
-
-    # ------------------------------------------------------------------
     def _decode(self, task, layout, rank, graph):
         lat_art = graph.artifacts[task.inputs[0]]
         out_art = graph.artifacts[task.outputs[0]]
@@ -449,14 +312,8 @@ class DiTPipeline:
                 [by_off[o] for o in sorted(by_off)], axis=0)
         else:
             tokens = lat_art.data[leader]["latent"]           # (N, pd) full
-        f, h, w, c = task.meta.get("latent_shape") or \
-            self._infer_latent_shape(graph)
         lat = dit.unpatchify(jnp.asarray(tokens)[None],
-                             (1, f, h, w, c), self.cfg.dit.patch_size)
+                             (1, *task.meta["latent_shape"]),
+                             self.cfg.dit.patch_size)
         pixels = vae.decode(self.weights(rank)[2], lat, self.cfg)[0]
         out_art.data[leader]["pixels"] = np.asarray(pixels)
-
-    def _infer_latent_shape(self, graph):
-        req = graph.request
-        f = max(1, (req.frames + 3) // 4) if req.frames > 1 else 1
-        return (f, req.height // 8, req.width // 8, self.cfg.dit.in_channels)

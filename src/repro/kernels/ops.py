@@ -52,8 +52,9 @@ def interpret_mode() -> bool:
 @dataclasses.dataclass
 class SplicedKV:
     """A §11 hit-path KV stream: the stale snapshot plus this step's
-    fresh local shard at ``offset`` — handed to :func:`splice_attention`
-    so the spliced tensor is never materialized (DESIGN.md §12)."""
+    fresh local shard at ``offset``.  The model's layer splices it: the
+    Pallas path through :func:`splice_attention`, so the spliced tensor
+    is never materialized, the jnp path in its program (DESIGN.md §12)."""
     k_stale: Any                  # (B, N_total, KV, d)
     v_stale: Any
     k_fresh: Any                  # (B, N_local, KV, d)

@@ -258,12 +258,21 @@ def _post(blocks, i, x, mods, txt, q, k, v, *, cfg):
 @functools.partial(jax.jit, static_argnames=("cfg", "offset"))
 def _post_spliced(blocks, i, x, mods, txt, q, k_stale, v_stale, k_fresh,
                   v_fresh, *, cfg, offset):
-    """``_post`` on a §11 cache hit: the splice kernel patches this
-    rank's fresh K/V into the stale snapshot's stream (the fresh rows'
-    ``offset`` lays out the kernel's blocks, so it is static)."""
+    """``_post`` on a §11 cache hit: this rank's fresh K/V at ``offset``
+    in the stale snapshot's stream (static: it lays out the kernel's
+    blocks).  The splice kernel patches the stream tile by tile; the jnp
+    path writes the fresh rows in and attends as ``_post`` does, so its
+    hit steps equal materializing the splice first, bit for bit."""
     built()
-    attn = ops.splice_attention(q, k_stale, v_stale, k_fresh, v_fresh,
-                                offset=offset, use_pallas=True)
+    if cfg.use_pallas:
+        attn = ops.splice_attention(q, k_stale, v_stale, k_fresh, v_fresh,
+                                    offset=offset, use_pallas=True)
+    else:
+        k = jax.lax.dynamic_update_slice_in_dim(k_stale, k_fresh, offset,
+                                                axis=1)
+        v = jax.lax.dynamic_update_slice_in_dim(v_stale, v_fresh, offset,
+                                                axis=1)
+        attn = L.sdpa(q, k, v, causal=False)
     return _post_rest(layer_of(blocks, i), x, mods, txt, attn, cfg)
 
 
@@ -273,7 +282,7 @@ def _adaln_layer(blocks, i, x, ctx, kv_gather, layer, cfg):
     c, txt = ctx
     mods, q, k, v = _pre(blocks, i, x, c, cfg=cfg)
     kv = kv_gather(k, v, layer)                 # GFC all-gather (axis=1)
-    if isinstance(kv, ops.SplicedKV):           # §11 hit, fused splice
+    if isinstance(kv, ops.SplicedKV):           # §11 hit
         return _post_spliced(blocks, i, x, mods, txt, q, kv.k_stale,
                              kv.v_stale, kv.k_fresh, kv.v_fresh, cfg=cfg,
                              offset=kv.offset)
@@ -341,13 +350,12 @@ def forward_sp_tokens(params, tok_shard, t, txt_embeds, cfg: ModelConfig, *,
     identity at SP1).  Queries stay local, so compute is token-sharded
     while attention sees the full sequence — the paper's elastic SP
     layout.  The layer index (counted over every block kind) keys the
-    cross-step feature cache (DESIGN.md §11): a cache-hit gather returns
-    the stale remote shards of THIS layer from the previous refresh step
-    with the fresh local shard spliced in, skipping the collective
-    entirely.  On the Pallas fast path the hit gather instead returns a
-    :class:`ops.SplicedKV` and the splice happens inside the attention
-    kernel's K/V stream — the concatenated tensors never materialize
-    (DESIGN.md §12).
+    cross-step feature cache (DESIGN.md §11): a cache-hit gather skips
+    the collective and returns a :class:`ops.SplicedKV`, the stale K/V of
+    THIS layer from the previous refresh step beside the fresh local
+    shard.  The layer splices them: inside the attention kernel's K/V
+    stream on the Pallas path, so the spliced tensors never materialize,
+    or by an in-program update on the jnp path (DESIGN.md §12).
 
     Between gathers the step runs compiled: the family's head, ``pre``
     and ``post`` per layer of each of its block kinds (:data:`FAMILIES`),

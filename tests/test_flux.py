@@ -12,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs.dit_models import DIT_IMAGE, FLUX1_DEV
+from repro.configs.dit_models import DIT_IMAGE, DIT_VIDEO, FLUX1_DEV
 from repro.core.cost_model import CostModel
 from repro.core.gfc import GroupFreeComm
 from repro.core.policies import make_policy
@@ -177,6 +177,15 @@ def test_cfg_branch_count_is_decided_from_the_model():
     # FLUX's layers take no §11 snapshot, so its graph declares no cache
     assert cache_artifact(g_flux) is None
     assert len(cache_artifact(g_pixart).fields) == 2 * 2    # k, v a layer
+    # every denoise task and the decode carry the latent's one shape
+    video = Request(id="v", model="dit-video", height=64, width=128,
+                    frames=9, steps=2, arrival=0.0)
+    for g, shape in ((g_pixart, (1, 16, 16, 16)),
+                     (convert_request(video, DIT_VIDEO.reduced()),
+                      (3, 8, 16, 16))):
+        tasks = [t for t in g.tasks.values() if t.kind != "encode"]
+        assert {t.kind for t in tasks} == {"denoise", "decode"}
+        assert {t.meta["latent_shape"] for t in tasks} == {shape}
 
 
 def test_cache_plane_never_stamps_a_flux_hit():
