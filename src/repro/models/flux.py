@@ -14,8 +14,10 @@ After BFL's ``flux/model.py`` and ``flux/modules/layers.py``:
   projection and GELU(tanh) MLP; the two meet in one attention over
   ``[text; image]``;
 * a single-stream block over ``[text; image]``: one 3-row modulation,
-  ``linear1`` giving q, k, v and the MLP input together, ``linear2`` on
-  ``concat(attention, gelu(mlp))``, one gated residual;
+  ``linear1`` giving q, k, v and the MLP input together (one drawn
+  weight, run as four matmuls that each write their own output,
+  DESIGN.md §18), ``linear2`` on ``concat(attention, gelu(mlp))``, one
+  gated residual;
 * the final layer: shift and scale from ``vec``, a norm, a linear head.
 
 The served step's state is one (B, L + N_local, D) array: the text
@@ -189,6 +191,19 @@ def _qkv(y, p, cfg: ModelConfig, cos, sin):
     return q, k, y[:, :, 2]
 
 
+def _linear1(blocks, i, cfg: ModelConfig):
+    """Single block ``i``'s ``linear1`` as (weight, bias) pairs of q, k, v
+    and the MLP input: column blocks of the one drawn (D, 3D + F) weight,
+    each read from the stacked weight by a dynamic slice that XLA fuses
+    into the matmul consuming it, so no part of the weight is copied."""
+    d = cfg.d_model
+    w, b = blocks["linear1_w"], blocks["linear1_b"]
+    cuts = (0, d, 2 * d, 3 * d, w.shape[-1])
+    return [(jax.lax.dynamic_slice(w, (i, 0, lo), (1, d, hi - lo))[0],
+             jax.lax.dynamic_slice(b, (i, lo), (1, hi - lo))[0])
+            for lo, hi in zip(cuts, cuts[1:])]
+
+
 def _attend(q, k_txt, v_txt, k_img, v_img, cfg: ModelConfig):
     """This rank's rows over ``[text K; image K]``, heads merged."""
     k = jnp.concatenate([k_txt, k_img], axis=1)
@@ -274,16 +289,25 @@ def _double_post(blocks, i, x, m_txt, m_img, q, k_txt, v_txt, k_img, v_img,
 @functools.partial(jax.jit, static_argnames=("cfg",))
 def _single_pre(blocks, i, x, vec, cos, sin, *, cfg):
     """Single block ``i`` up to the gather: modulation rows, modulated
-    norm, ``linear1`` split into q, k, v and the MLP input."""
+    norm, and ``linear1`` as four matmuls, each written at its own width
+    where its consumer reads it: q and k to their norm and rotation, v
+    and the MLP input as they are."""
     built()
-    p = layer_of(blocks, i)
-    lt, d3 = cfg.dit.text_len, 3 * cfg.d_model
+    p = layer_of({k: a for k, a in blocks.items()
+                  if not k.startswith("linear1")}, i)
+    lt = cfg.dit.text_len
     mods = jax.nn.silu(vec) @ p["mod_w"] + p["mod_b"]
     sh, sc, _ = jnp.split(mods, 3, axis=-1)
     h = mod_norm(x, sh, sc, up=cfg.use_pallas)
-    y = h @ p["linear1_w"] + p["linear1_b"]
-    q, k, v = _qkv(y[..., :d3], p, cfg, cos, sin)
-    return mods, q, y[..., d3:], k[:, :lt], v[:, :lt], k[:, lt:], v[:, lt:]
+    # the barrier keeps XLA from folding the head split below into the
+    # matmul, which then converts its weight in a pass of its own
+    q, k, v, mlp = (jax.lax.optimization_barrier(h @ w + b)
+                    for w, b in _linear1(blocks, i, cfg))
+    q, k, v = (y.reshape(y.shape[:2] + (cfg.num_heads, cfg.head_dim))
+               for y in (q, k, v))
+    q = _rope(L.rmsnorm(p["q_scale"], q), cos, sin)
+    k = _rope(L.rmsnorm(p["k_scale"], k), cos, sin)
+    return mods, q, mlp, k[:, :lt], v[:, :lt], k[:, lt:], v[:, lt:]
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))

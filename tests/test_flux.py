@@ -5,6 +5,7 @@ with degree 1; a guided request is one row everywhere the control plane
 and the executor count rows; packs of guided requests match solo steps;
 and the §11 cache-hit splice, which assumes no text rows ahead of the
 image rows, refuses a two-stream model."""
+import functools
 import threading
 
 import jax
@@ -25,7 +26,8 @@ from repro.diffusion.adapters import convert_request
 from repro.diffusion.feature_cache import FeatureCachePlane, cache_artifact
 from repro.diffusion.pipeline import DiTPipeline
 from repro.kernels import ops
-from repro.models import dit
+from repro.models import dit, flux
+from repro.models.dit_parts import layer_of, mod_norm
 from repro.serving.cache_demo import liven
 from repro.serving.engine import ServingEngine
 from test_serving_engine import FixedSP
@@ -95,6 +97,99 @@ def test_kernel_path_matches_the_jnp_path(params):
     want = _forward(params, JNP, tok, t, txt, g)
     assert np.abs(want).max() > 0.01
     np.testing.assert_allclose(got, want, **TOL)
+
+
+def _layer_inputs(params, cfg, seed):
+    """The state and (vec, cos, sin) a block's programs take, from the
+    head at degree 1."""
+    tok, t, txt, g = _inputs(seed, batch=2)
+    return flux.head(params, tok, t, txt, cfg, pos_offset=0, n_total=N,
+                     guidance=g, grids=(GRID,) * 2)
+
+
+def test_linear1_pieces_are_the_drawn_weight_by_columns(params):
+    """q, k, v and the MLP input read column blocks of the one drawn
+    ``linear1``, which together are that weight and bias to the bit."""
+    blocks = params["single"]
+    d, f = CFG.d_model, CFG.d_ff
+    assert blocks["linear1_w"].shape[1:] == (d, 3 * d + f)
+    for i in range(CFG.dit.num_single_layers):
+        pieces = flux._linear1(blocks, i, CFG)
+        assert [w.shape for w, _ in pieces] == [(d, d)] * 3 + [(d, f)]
+        np.testing.assert_array_equal(
+            np.concatenate([w for w, _ in pieces], axis=1),
+            blocks["linear1_w"][i])
+        np.testing.assert_array_equal(
+            np.concatenate([b for _, b in pieces]), blocks["linear1_b"][i])
+
+
+def _fused_single_pre(blocks, i, x, vec, cos, sin, cfg):
+    """The single block's ``pre`` as one (D, 3D + F) matmul whose output
+    is then sliced into q, k, v and the MLP input."""
+    p = layer_of(blocks, i)
+    lt, d3 = cfg.dit.text_len, 3 * cfg.d_model
+    mods = jax.nn.silu(vec) @ p["mod_w"] + p["mod_b"]
+    sh, sc, _ = jnp.split(mods, 3, axis=-1)
+    h = mod_norm(x, sh, sc, up=cfg.use_pallas)
+    y = h @ p["linear1_w"] + p["linear1_b"]
+    q, k, v = flux._qkv(y[..., :d3], p, cfg, cos, sin)
+    return mods, q, y[..., d3:], k[:, :lt], v[:, :lt], k[:, lt:], v[:, lt:]
+
+
+@pytest.mark.parametrize("cfg", [CFG, JNP], ids=["kernel", "jnp"])
+def test_single_block_matches_the_fused_linear1(params, cfg):
+    x, (vec, cos, sin) = _layer_inputs(params, cfg, 11)
+    blocks = params["single"]
+    with jax.default_matmul_precision("highest"):
+        for i in range(cfg.dit.num_single_layers):
+            want = _fused_single_pre(blocks, i, x, vec, cos, sin, cfg)
+            got = flux._single_pre(blocks, i, x, vec, cos, sin, cfg=cfg)
+            for a, b in zip(got, want):
+                assert a.shape == b.shape
+                np.testing.assert_allclose(a, b, **TOL)
+            out = flux.single_layer(blocks, i, x, (vec, cos, sin),
+                                    lambda k, v, layer: (k, v), i, cfg)
+            np.testing.assert_allclose(
+                out, flux._single_post(blocks, i, x, *want, cfg=cfg), **TOL)
+            assert np.abs(np.asarray(out - x)).max() > 0.01
+            x = out
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in it."""
+    for eqn in jaxpr.eqns:
+        subs = [getattr(v, "jaxpr", v) for v in eqn.params.values()
+                if hasattr(v, "eqns") or hasattr(getattr(v, "jaxpr", None),
+                                                  "eqns")]
+        if subs:
+            for sub in subs:
+                yield from _eqns(sub)
+        else:
+            yield eqn
+
+
+@pytest.mark.parametrize("cfg", [CFG, JNP], ids=["kernel", "jnp"])
+def test_single_pre_never_slices_linear1s_output(params, cfg):
+    """No value of ``linear1``'s whole width 3D + F is computed in
+    ``_single_pre``: the stacked weight and bias are only read, a column
+    block at a time, by the dynamic slices feeding the four matmuls."""
+    x, (vec, cos, sin) = _layer_inputs(params, cfg, 12)
+    wide = 3 * cfg.d_model + cfg.d_ff
+    closed = jax.make_jaxpr(functools.partial(flux._single_pre, cfg=cfg))(
+        params["single"], 0, x, vec, cos, sin)
+    reads = []
+    for eqn in _eqns(closed.jaxpr):
+        assert all(v.aval.shape[-1:] != (wide,) for v in eqn.outvars), eqn
+        if any(v.aval.shape[-1:] == (wide,) for v in eqn.invars
+               if hasattr(v, "aval")):
+            assert eqn.primitive.name == "dynamic_slice", eqn
+            reads.append(eqn.outvars[0].aval.shape[-1])
+    d, f = cfg.d_model, cfg.d_ff
+    assert sorted(reads) == sorted([d, d, d, f] * 2)   # weight and bias
+    dots = [e.outvars[0].aval.shape[-1] for e in _eqns(closed.jaxpr)
+            if e.primitive.name == "dot_general"
+            and e.outvars[0].aval.ndim == 3]
+    assert sorted(dots) == [d, d, d, f]
 
 
 def test_guidance_and_positions_are_inputs(params):

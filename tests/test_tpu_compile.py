@@ -259,3 +259,14 @@ def test_flux_programs_compile_at_served_width(one_chip, compiled_kernels,
     m = compiled.memory_analysis()
     assert weights + m.output_size_in_bytes + m.temp_size_in_bytes \
         < HBM_BYTES
+    if program == "_single_pre":
+        # linear1's q, k, v and MLP input are written at their own widths:
+        # no buffer of its whole width, no slice or copy of a part of one,
+        # and no copy of its weight or a column block of it (float32 or
+        # the bfloat16 a matmul reads), each read inside its matmul
+        entry = hlo[hlo.index("\nENTRY"):]
+        assert f"f32[{b},{s},{3 * d + cfg.d_ff}]" not in entry
+        for width in (3 * d, cfg.d_ff):
+            assert not re.search(
+                rf"= f32\[{b},{s},{width}\]\S* (slice|copy)\(", entry)
+        assert not re.search(rf"= \w+\[{d},\d+\]", entry)
